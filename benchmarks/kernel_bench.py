@@ -98,14 +98,14 @@ def paged_decode_rows(smoke: bool = False) -> list[tuple]:
     n_pages = b_ * w
     kv_bits = 8
     q = jnp.asarray(rng.normal(size=(b_, hkv * g, d)), jnp.float32)
-    kp = jnp.asarray(rng.integers(-127, 128, (1, n_pages, ps, hkv, d)), jnp.int8)
-    vp = jnp.asarray(rng.integers(-127, 128, (1, n_pages, ps, hkv, d)), jnp.int8)
-    ks = jnp.asarray(rng.random((1, n_pages, ps, hkv, 1)) * 0.1, jnp.float32)
-    vs = jnp.asarray(rng.random((1, n_pages, ps, hkv, 1)) * 0.1, jnp.float32)
+    kp = jnp.asarray(rng.integers(-127, 128, (1, n_pages, hkv, ps, d)), jnp.int8)
+    vp = jnp.asarray(rng.integers(-127, 128, (1, n_pages, hkv, ps, d)), jnp.int8)
+    ks = jnp.asarray(rng.random((1, n_pages, hkv, ps)) * 0.1, jnp.float32)
+    vs = jnp.asarray(rng.random((1, n_pages, hkv, ps)) * 0.1, jnp.float32)
     nk = jnp.asarray(rng.integers(-127, 128, (b_, hkv, d)), jnp.int8)
     nv = jnp.asarray(rng.integers(-127, 128, (b_, hkv, d)), jnp.int8)
-    nks = jnp.asarray(rng.random((b_, hkv, 1)) * 0.1, jnp.float32)
-    nvs = jnp.asarray(rng.random((b_, hkv, 1)) * 0.1, jnp.float32)
+    nks = jnp.asarray(rng.random((b_, hkv)) * 0.1, jnp.float32)
+    nvs = jnp.asarray(rng.random((b_, hkv)) * 0.1, jnp.float32)
     tables = jnp.asarray(
         rng.permutation(n_pages).reshape(b_, w).astype(np.int32)
     )
@@ -120,7 +120,9 @@ def paged_decode_rows(smoke: bool = False) -> list[tuple]:
         vd = ref.gather_pages(vp, tables)[0].at[rows_idx, lengths].set(nv)
         ksd = ref.gather_pages(ks, tables)[0].at[rows_idx, lengths].set(nks)
         vsd = ref.gather_pages(vs, tables)[0].at[rows_idx, lengths].set(nvs)
-        return ref.mqa_decode_ref(q, kd, vd, ksd, vsd, lengths + 1, sm_scale=sm)
+        return ref.mqa_decode_ref(
+            q, kd, vd, ksd[..., None], vsd[..., None], lengths + 1, sm_scale=sm
+        )
 
     def paged_path(lengths):
         return ops.paged_mqa_decode(

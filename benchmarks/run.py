@@ -132,6 +132,9 @@ def main() -> None:
         help="write smoke rows as JSON (bench-trajectory artifact)",
     )
     args = parser.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         smoke(args.json)
         return

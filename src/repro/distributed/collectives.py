@@ -59,8 +59,7 @@ def compressed_psum_mean(
     e2_residual): add e1_residual to next round's x.  When e2 is None only
     the value is returned (residuals dropped; fine for one-shot reductions).
     """
-    # jax.lax.axis_size only exists in newer jax; psum(1) is the portable form
-    n_shards = jax.lax.psum(1, axis)
+    n_shards = jax.lax.axis_size(axis)
     shape, n = x.shape, x.size
     flat = x.reshape(-1).astype(jnp.float32)
     q, scale = _quantize_chunks(flat, n_shards)  # [S, m/C, C] int8

@@ -16,6 +16,7 @@ import jax
 from jax.sharding import Mesh
 
 from repro.distributed.sharding import tree_shardings
+from repro.launch.mesh import make_mesh
 
 
 def make_elastic_mesh(n_devices: Optional[int] = None, model_parallel: int = 1) -> Mesh:
@@ -27,10 +28,7 @@ def make_elastic_mesh(n_devices: Optional[int] = None, model_parallel: int = 1) 
     if n % model_parallel:
         raise ValueError(f"{n} devices not divisible by model_parallel={model_parallel}")
     data = n // model_parallel
-    return jax.make_mesh((data, model_parallel), ("data", "model"),
-                         devices=devs[:n]) if hasattr(jax, "make_mesh") else Mesh(
-        jax.numpy.array(devs[:n]).reshape(data, model_parallel), ("data", "model")
-    )
+    return make_mesh((data, model_parallel), ("data", "model"), devices=devs[:n])
 
 
 def reshard_state(state, mesh: Mesh):

@@ -46,19 +46,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["mpmm_pallas", "DEFAULT_BLOCKS"]
+from repro.quant.pack import int4_nibbles
 
-# jax < 0.5 names it TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+__all__ = ["mpmm_pallas", "DEFAULT_BLOCKS"]
 
 DEFAULT_BLOCKS = dict(bm=128, bn=128, bk=512)
 
 
 def _unpack_w4(packed: jnp.ndarray) -> jnp.ndarray:
     """[bk//2, bn] int8 (two nibbles per byte along K) -> [bk, bn] int8."""
-    lo = (packed << 4) >> 4  # arithmetic shifts sign-extend the low nibble
-    hi = packed >> 4
-    return jnp.stack([lo, hi], axis=1).reshape(packed.shape[0] * 2, packed.shape[1])
+    lo, hi = int4_nibbles(packed)
+    k2, n = packed.shape[0] * 2, packed.shape[1]
+    return jnp.stack([lo, hi], axis=1).reshape(k2, n).astype(jnp.int8)
 
 
 def _digit_planes(v: jnp.ndarray, bits: int):
@@ -224,7 +223,7 @@ def mpmm_pallas(
             out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
             out_shape=jax.ShapeDtypeStruct((m_sz, n_sz), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")
             ),
             interpret=interpret,
@@ -250,7 +249,7 @@ def mpmm_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda k, m, n: (m, n)),
         out_shape=jax.ShapeDtypeStruct((m_sz, n_sz), acc_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "parallel", "parallel")
         ),
         interpret=interpret,

@@ -22,18 +22,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["mqa_decode_pallas"]
+from repro.quant.pack import int4_nibbles
 
-# jax < 0.5 names it TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+__all__ = ["mqa_decode_pallas"]
 
 _NEG_INF = -1e30
 
 
 def _unpack_kv4(packed: jnp.ndarray) -> jnp.ndarray:
-    """[bs, D//2] int8 -> [bs, D] int8 (nibbles packed along head_dim)."""
-    lo = (packed << 4) >> 4
-    hi = packed >> 4
+    """[bs, D//2] int8 -> [bs, D] int32 (nibbles packed along head_dim)."""
+    lo, hi = int4_nibbles(packed)
     return jnp.stack([lo, hi], axis=-1).reshape(packed.shape[0], packed.shape[1] * 2)
 
 
@@ -148,7 +146,7 @@ def mqa_decode_pallas(
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

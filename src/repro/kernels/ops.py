@@ -3,8 +3,11 @@
 These handle what the raw kernels don't: padding to block multiples, block
 size selection, FF/CF dataflow selection (via the same core.dataflow selector
 the conv mapper uses — a matmul is a 1x1 conv), weight packing/quantization,
-KV-cache quantization, and platform dispatch (Pallas interpret mode on CPU,
-compiled on TPU; an XLA-native fallback is available for A/B tests).
+KV-cache quantization, and platform dispatch: the platform decides between
+compiled Pallas (TPU) and interpret mode (elsewhere).  The paged attention
+ops the serving engine calls pick the compiled kernel on a TPU and their
+slot-scan XLA fallback elsewhere.  The model's quantized projections call
+``mpmm(..., backend="xla")``, the XLA dequant reference, on every platform.
 """
 from __future__ import annotations
 
@@ -48,21 +51,42 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def pack_weights(w: jnp.ndarray, bits: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-output-channel symmetric quantization of a [K, N] weight matrix.
+def _paged_backend_default() -> str:
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
-    Returns (w_data, w_scale): w_data is [K, N] int8/int16, or [K//2, N] int8
-    with two K-consecutive nibbles per byte when bits == 4 (SPEED's unified
-    elements along the reduction dim); w_scale is [1, N] f32.
-    """
+
+@jax.jit
+def _abs_max(w: jnp.ndarray) -> jnp.ndarray:
+    return jnp.maximum(
+        jnp.max(jnp.abs(w.astype(jnp.float32)), axis=-2, keepdims=True), 1e-30
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("bits",))
+def _quantize(w: jnp.ndarray, scale: jnp.ndarray, bits: int) -> jnp.ndarray:
     prec = Precision.from_bits(bits)
-    amax = jnp.maximum(jnp.max(jnp.abs(w), axis=0, keepdims=True), 1e-30)
-    scale = (amax / prec.spec.qmax).astype(jnp.float32)
-    q = jnp.clip(jnp.round(w / scale), prec.spec.qmin, prec.spec.qmax)
+    q = jnp.clip(jnp.round(w.astype(jnp.float32) / scale), prec.spec.qmin, prec.spec.qmax)
     q = q.astype(_INT_DTYPE[bits])
-    if bits == 4:
-        q = pack_int4(q, axis=0)
-    return q, scale
+    return pack_int4(q, axis=-2) if bits == 4 else q
+
+
+def pack_weights(w: jnp.ndarray, bits: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-output-channel symmetric quantization of a [..., K, N] weight
+    (leading dims — stacked layers, experts — quantize independently).
+
+    Returns (w_data, w_scale): w_data is [..., K, N] int8/int16, or
+    [..., K//2, N] int8 with two K-consecutive nibbles per byte when
+    bits == 4 (SPEED's unified elements along the reduction dim); w_scale is
+    [..., 1, N] f32.
+
+    The reduction and the quantization are one jitted pass each, so a
+    full-width leaf never sits next to f32 copies of itself.  The scale
+    division stays outside jit on purpose: inside, XLA turns a division by
+    a constant into a multiplication by its reciprocal, which moves some
+    scales (and so payloads) by one ulp.
+    """
+    scale = (_abs_max(w) / Precision.from_bits(bits).spec.qmax).astype(jnp.float32)
+    return _quantize(w, scale, bits), scale
 
 
 def select_matmul_dataflow(m: int, n: int, k: int) -> Dataflow:
@@ -276,16 +300,16 @@ def mqa_decode(
 @functools.partial(jax.jit, static_argnames=("kv_bits", "backend", "interpret"))
 def paged_mqa_decode(
     q: jnp.ndarray,  # [B, H, D]
-    k_pool: jnp.ndarray,  # [L, P, ps, Hkv, D (/2 if kv_bits==4)]
+    k_pool: jnp.ndarray,  # [L, P, Hkv, ps, D (/2 if kv_bits==4)]
     v_pool: jnp.ndarray,
-    k_scale,  # [L, P, ps, Hkv, 1] f32, or None when kv_bits == 16
+    k_scale,  # [L, P, Hkv, ps] f32, or None when kv_bits == 16
     v_scale,
     tables: jnp.ndarray,  # [B, W] int32 page tables (zero-padded)
     lengths: jnp.ndarray,  # [B] int32 — tokens already in the cache
     layer,  # int32 — pool layer to attend against
     new_k: jnp.ndarray,  # [B, Hkv, D (/2)] this step's token, not yet stored
     new_v: jnp.ndarray,
-    new_k_scale=None,  # [B, Hkv, 1] f32, or None
+    new_k_scale=None,  # [B, Hkv] f32, or None
     new_v_scale=None,
     *,
     kv_bits: int = 8,
@@ -298,16 +322,16 @@ def paged_mqa_decode(
     Reads only the pages each row's table points at (up to its length), and
     folds the step's own not-yet-stored token into the online softmax, so no
     contiguous cache view ever materializes.  ``backend=None`` picks the
-    Pallas kernel on TPU and the slot-scan XLA fallback elsewhere (compiled
-    Pallas-on-CPU isn't a thing; interpret mode is for correctness tests).
-    Only ``window``'s *presence* is static — its value may be traced.
+    compiled Pallas kernel on TPU and the slot-scan XLA fallback elsewhere
+    (compiled Pallas-on-CPU isn't a thing; interpret mode is for correctness
+    tests).  Only ``window``'s *presence* is static — its value may be traced.
     """
     if interpret is None:
         interpret = _interpret_default()
     if backend is None:
-        backend = "xla" if jax.default_backend() != "tpu" else "pallas"
+        backend = _paged_backend_default()
     b, h, d = q.shape
-    hkv = k_pool.shape[3]
+    hkv = k_pool.shape[2]
     qg = q.reshape(b, hkv, h // hkv, d)
     sm_scale = 1.0 / float(np.sqrt(d))
     args = (
@@ -342,9 +366,9 @@ def paged_mqa_decode(
 @functools.partial(jax.jit, static_argnames=("kv_bits", "backend", "interpret"))
 def paged_mqa_prefill(
     q: jnp.ndarray,  # [B, C, H, D] — a chunk of C query tokens per row
-    k_pool: jnp.ndarray,  # [L, P, ps, Hkv, D (/2 if kv_bits==4)]
+    k_pool: jnp.ndarray,  # [L, P, Hkv, ps, D (/2 if kv_bits==4)]
     v_pool: jnp.ndarray,
-    k_scale,  # [L, P, ps, Hkv, 1] f32, or None when kv_bits == 16
+    k_scale,  # [L, P, Hkv, ps] f32, or None when kv_bits == 16
     v_scale,
     tables: jnp.ndarray,  # [B, W] int32 page tables (zero-padded)
     ctx_lens: jnp.ndarray,  # [B] int32 — tokens already in the pool
@@ -352,7 +376,7 @@ def paged_mqa_prefill(
     layer,  # int32 — pool layer to attend against
     chunk_k: jnp.ndarray,  # [B, C, Hkv, D (/2)] this chunk's K, not yet stored
     chunk_v: jnp.ndarray,
-    chunk_k_scale=None,  # [B, C, Hkv, 1] f32, or None
+    chunk_k_scale=None,  # [B, C, Hkv] f32, or None
     chunk_v_scale=None,
     *,
     kv_bits: int = 8,
@@ -369,12 +393,12 @@ def paged_mqa_prefill(
     if interpret is None:
         interpret = _interpret_default()
     if backend is None:
-        backend = "xla" if jax.default_backend() != "tpu" else "pallas"
+        backend = _paged_backend_default()
     b, c, h, d = q.shape
-    hkv = k_pool.shape[3]
-    # [B, C, H, D] -> [B, Hkv, C, G, D]; chunk K/V -> [B, Hkv, C, Dk]
+    hkv = k_pool.shape[2]
+    # [B, C, H, D] -> [B, Hkv, C, G, D]; chunk K/V [B, C, Hkv, ...] -> [B, Hkv, C, ...]
     qg = q.reshape(b, c, hkv, h // hkv, d).transpose(0, 2, 1, 3, 4)
-    t = lambda x: None if x is None else x.transpose(0, 2, 1, 3)
+    t = lambda x: None if x is None else jnp.swapaxes(x, 1, 2)
     sm_scale = 1.0 / float(np.sqrt(d))
     args = (
         qg,

@@ -1,26 +1,36 @@
 """Paged flash-decode GQA attention: index the KV page pool in place.
 
 The serving engine stores every request's KV cache as fixed-size pages in one
-shared pool (``serve/kv_cache.py``); a request's *page table* lists its pages
-in order.  The old decode path gathered the pool into a contiguous
-``[L, B, S, Hkv, D]`` view every token — an O(layers x batch x max_seq) HBM
-copy that dwarfed the attention math.  This kernel reads the pool through the
-page table instead, PagedAttention-style:
+shared pool (``serve/kv_cache.py``), head-major so one (page, kv head) pair
+is a contiguous ``[ps, Dk]`` tile:
+
+    payload: [L, P, Hkv, ps, Dk]     scales: [L, P, Hkv, ps] f32
+
+A request's *page table* lists its pages in order.  This kernel reads the
+pool through the page table, PagedAttention-style:
 
 * grid ``(batch, kv_head, page_slot)`` with the slot dimension innermost and
   sequential; the block-spec index map translates ``(row, slot) -> page_id``
-  via a scalar-prefetched table, so each grid step DMAs exactly one page.
+  via a scalar-prefetched table, so each grid step DMAs exactly one
+  ``[ps, Dk]`` page tile (its two minor dimensions are whole array
+  dimensions, which is what the TPU block-shape rule asks).
 * slots at or beyond a row's occupied length are *clamped* to the row's last
   live page: consecutive grid steps then ask for the same block and Pallas
   elides the re-fetch — dead slots cost neither DMA nor (via ``pl.when``)
   compute.  Per-token attention traffic is proportional to the row's actual
   cache length, not the table capacity.
-* int8/int4 payloads are dequantized in-register with per-(token, head)
-  scales, exactly like ``kernels/mqa_decode.py``; bf16 pools skip the scales.
+* int8/int4 payloads are dequantized in-register.  Per-(token, head) scales
+  arrive as a ``[Hkv, ps]`` block per page; the kernel picks its head's row
+  and applies it to the ``[G, ps]`` scores and probabilities (a row of
+  scales over the key axis) rather than to the ``[ps, Dk]`` tile.  int4
+  bytes hold head-dim pairs ``(2j, 2j+1)`` as (low, high) nibbles; the kernel
+  never re-interleaves them — queries and outputs travel as (even, odd)
+  head-dim planes, so ``q . k = q_even . k_lo + q_odd . k_hi``.  bf16 pools
+  skip the scales.
 * the *new* token's K/V (computed this step, not yet in the pool) enters the
-  online softmax as one extra term in the final grid step, so the caller
-  never round-trips it through a gathered view — it scatters the quantized
-  payload straight into its page afterwards (``pool.at[:, page, off].set``).
+  online softmax as one extra term in the final grid step, dequantized to
+  f32 by the wrapper, so the caller never round-trips it through a gathered
+  view — it scatters the quantized payload straight into its page afterwards.
 
 ``paged_mqa_decode_xla`` is the XLA fallback for CPU/interpret runs: a
 ``lax.scan`` over page slots that gathers one page per live slot through the
@@ -37,15 +47,67 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.mqa_decode import _unpack_kv4
-from repro.quant.pack import unpack_int4
+from repro.quant.pack import int4_nibbles, unpack_int4
 
-__all__ = ["paged_mqa_decode_pallas", "paged_mqa_decode_xla"]
-
-# jax < 0.5 names it TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+__all__ = [
+    "paged_mqa_decode_pallas",
+    "paged_mqa_decode_xla",
+    "dequant_kv",
+    "to_planes",
+    "from_planes",
+    "payload_planes",
+]
 
 _NEG_INF = -1e30
+
+
+# ------------------------------------------------- shared by both paged kernels
+def dequant_kv(payload, scale, kv_bits: int) -> jnp.ndarray:
+    """[..., Dk] payload + [...] scale -> [..., D] f32 (XLA side)."""
+    if kv_bits == 4:
+        payload = unpack_int4(payload, axis=-1)
+    out = payload.astype(jnp.float32)
+    if scale is not None:
+        out = out * scale.astype(jnp.float32)[..., None]
+    return out
+
+
+def to_planes(x: jnp.ndarray, kv_bits: int, axis: int) -> jnp.ndarray:
+    """[..., D] -> [..., n_pl, ..., D/n_pl] with the plane axis inserted at
+    ``axis``: for kv4 the (even, odd) head-dim planes matching the pool's
+    (low, high) nibbles, otherwise one plane holding ``x`` as is."""
+    n_pl = 2 if kv_bits == 4 else 1
+    x = x.reshape(*x.shape[:-1], x.shape[-1] // n_pl, n_pl)
+    return jnp.moveaxis(x, -1, axis)
+
+
+def from_planes(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Inverse of :func:`to_planes`."""
+    x = jnp.moveaxis(x, axis, -1)
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def payload_planes(tile, kv_bits: int) -> tuple:
+    """In-kernel: one ``[n, Dk]`` payload tile -> its f32 head-dim planes
+    (the (low, high) nibbles for kv4, the tile itself otherwise)."""
+    if kv_bits == 4:
+        lo, hi = int4_nibbles(tile)
+        return lo.astype(jnp.float32), hi.astype(jnp.float32)
+    return (tile.astype(jnp.float32),)
+
+
+def dot_nt(a, b):
+    """a [M, K] @ b[N, K]^T -> [M, N] f32."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def dot_nn(a, b):
+    """a [M, K] @ b[K, N] -> [M, N] f32."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
 
 
 def _paged_kernel(
@@ -55,10 +117,10 @@ def _paged_kernel(
     win_lo_ref,  # [B] int32 — first in-window position (0 when no window)
     layer_ref,  # [1] int32
     # blocks
-    q_ref,  # [1, 1, G, D]
-    k_ref,  # [1, 1, ps, 1, Dk]   (one page of one kv head)
+    q_ref,  # [1, 1, n_pl, G, Dk] f32
+    k_ref,  # [1, 1, 1, ps, Dk]   (one page of one kv head)
     v_ref,
-    *rest,  # [ks_ref, vs_ref,] nk_ref, nv_ref, [nks_ref, nvs_ref,] o_ref + scratch
+    *rest,  # [ks_ref, vs_ref,] nk_ref, nv_ref, o_ref + scratch
     ps: int,
     kv_bits: int,
     sm_scale: float,
@@ -67,13 +129,13 @@ def _paged_kernel(
 ):
     quant = kv_bits < 16
     if quant:
-        ks_ref, vs_ref, nk_ref, nv_ref, nks_ref, nvs_ref = rest[:6]
-        o_ref, m_ref, l_ref, acc_ref = rest[6:]
+        ks_ref, vs_ref, nk_ref, nv_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
-        nk_ref, nv_ref = rest[:2]
-        o_ref, m_ref, l_ref, acc_ref = rest[2:]
+        nk_ref, nv_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    n_pl = q_ref.shape[2]
 
     b_idx = pl.program_id(0)
+    h_idx = pl.program_id(1)
     w_idx = pl.program_id(2)
     length = lengths_ref[b_idx]  # cache tokens; new token sits at `length`
 
@@ -83,7 +145,7 @@ def _paged_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # [G, D]
+    q = [q_ref[0, 0, i] for i in range(n_pl)]  # n_pl x [G, Dk]
 
     # window lower bound over the total (cache + new token) length
     lo = win_lo_ref[b_idx]
@@ -93,19 +155,13 @@ def _paged_kernel(
 
     @pl.when(block_live)
     def _update():
-        k = k_ref[0, 0, :, 0]  # [ps, Dk]
-        v = v_ref[0, 0, :, 0]
-        if kv_bits == 4:
-            k = _unpack_kv4(k)
-            v = _unpack_kv4(v)
-        kf = k.astype(jnp.float32)
-        vf = v.astype(jnp.float32)
+        kp = payload_planes(k_ref[0, 0, 0], kv_bits)  # n_pl x [ps, Dk]
+        vp = payload_planes(v_ref[0, 0, 0], kv_bits)
+        scores = dot_nt(q[0], kp[0])  # [G, ps]
+        for i in range(1, n_pl):
+            scores = scores + dot_nt(q[i], kp[i])
         if quant:
-            kf = kf * ks_ref[0, 0, :, 0].astype(jnp.float32)
-            vf = vf * vs_ref[0, 0, :, 0].astype(jnp.float32)
-        scores = jax.lax.dot_general(
-            q, kf, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [G, ps]
+            scores = scores * ks_ref[0, 0, pl.ds(h_idx, 1), :]  # [1, ps] row
         scores = scores * sm_scale
         pos = w_idx * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
         valid = pos < length
@@ -118,50 +174,43 @@ def _paged_kernel(
         p = jnp.exp(scores - m_new)
         p = jnp.where(valid, p, 0.0)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, vf, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        if quant:
+            p = p * vs_ref[0, 0, pl.ds(h_idx, 1), :]
+        for i in range(n_pl):
+            acc_ref[i] = acc_ref[i] * alpha + dot_nn(p, vp[i])
         m_ref[...] = m_new
 
     @pl.when(w_idx == n_w - 1)
     def _finish():
         # fused new-token term: the token produced this step attends to itself
         # (always inside any window — distance 0) without touching the pool.
-        nk = nk_ref[0]  # [1, Dk]
-        nv = nv_ref[0]
-        if kv_bits == 4:
-            nk = _unpack_kv4(nk)
-            nv = _unpack_kv4(nv)
-        nkf = nk.astype(jnp.float32)
-        nvf = nv.astype(jnp.float32)
-        if quant:
-            nkf = nkf * nks_ref[0, 0, 0].astype(jnp.float32)
-            nvf = nvf * nvs_ref[0, 0, 0].astype(jnp.float32)
-        s_new = jax.lax.dot_general(
-            q, nkf, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [G, 1]
+        nk = nk_ref[0, 0]  # [n_pl, Dk] f32, dequantized
+        nv = nv_ref[0, 0]
+        s_new = jnp.sum(q[0] * nk[0:1], axis=1, keepdims=True)  # [G, 1]
+        for i in range(1, n_pl):
+            s_new = s_new + jnp.sum(q[i] * nk[i : i + 1], axis=1, keepdims=True)
         s_new = s_new * sm_scale
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s_new)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s_new - m_new)  # [G, 1]
-        denom = l_ref[...] * alpha + p
-        acc = acc_ref[...] * alpha + p * nvf
-        o_ref[0, 0] = (acc / jnp.maximum(denom, 1e-20)).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...] * alpha + p, 1e-20)
+        for i in range(n_pl):
+            o_ref[0, 0, i] = (acc_ref[i] * alpha + p * nv[i : i + 1]) / denom
 
 
 def paged_mqa_decode_pallas(
     q: jnp.ndarray,  # [B, Hkv, G, D]
-    k_pool: jnp.ndarray,  # [L, P, ps, Hkv, Dk]  int8 payload or bf16
+    k_pool: jnp.ndarray,  # [L, P, Hkv, ps, Dk]  int8 payload or bf16
     v_pool: jnp.ndarray,
-    k_scale,  # [L, P, ps, Hkv, 1] f32, or None when kv_bits == 16
+    k_scale,  # [L, P, Hkv, ps] f32, or None when kv_bits == 16
     v_scale,
     tables: jnp.ndarray,  # [B, W] int32 page tables (zero-padded)
     lengths: jnp.ndarray,  # [B] int32 — tokens already in the cache
     layer: jnp.ndarray,  # [] or [1] int32 — which pool layer to read
     new_k: jnp.ndarray,  # [B, Hkv, Dk] — this step's K/V, not yet in the pool
     new_v: jnp.ndarray,
-    new_k_scale,  # [B, Hkv, 1] f32, or None
+    new_k_scale,  # [B, Hkv] f32, or None
     new_v_scale,
     *,
     kv_bits: int,
@@ -170,9 +219,9 @@ def paged_mqa_decode_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     b, hkv, g, d = q.shape
-    n_pages, ps = k_pool.shape[1], k_pool.shape[2]
-    dk = k_pool.shape[-1]
+    ps, dk = k_pool.shape[3], k_pool.shape[4]
     n_w = tables.shape[1]
+    n_pl = d // dk
     quant = kv_bits < 16
     lengths = lengths.astype(jnp.int32)
     # per-row first in-window position over the total (cache + new) length;
@@ -181,6 +230,9 @@ def paged_mqa_decode_pallas(
         win_lo = jnp.maximum(lengths + 1 - jnp.asarray(window, jnp.int32), 0)
     else:
         win_lo = jnp.zeros_like(lengths)
+    qp = to_planes(q.astype(jnp.float32), kv_bits, axis=2)  # [B, Hkv, n_pl, G, Dk]
+    nk = to_planes(dequant_kv(new_k, new_k_scale, kv_bits), kv_bits, axis=2)
+    nv = to_planes(dequant_kv(new_v, new_v_scale, kv_bits), kv_bits, axis=2)
 
     def page_map(b_, h_, w_, tables_ref, lengths_ref, win_lo_ref, layer_ref):
         # Clamp dead slots to the row's nearest live page — below the window
@@ -190,37 +242,26 @@ def paged_mqa_decode_pallas(
         n_live = (lengths_ref[b_] + ps - 1) // ps
         first = win_lo_ref[b_] // ps  # 0 when no window
         slot = jnp.clip(jnp.maximum(w_, first), 0, jnp.maximum(n_live - 1, 0))
-        return (layer_ref[0], tables_ref[b_, slot], 0, h_, 0)
+        return (layer_ref[0], tables_ref[b_, slot], h_, 0, 0)
+
+    def scale_map(b_, h_, w_, *refs):
+        page = page_map(b_, h_, w_, *refs)
+        return (page[0], page[1], 0, 0)
 
     def head_map(b_, h_, w_, *_):
-        return (b_, h_, 0, 0)
+        return (b_, h_, 0, 0, 0)
 
     def tok_map(b_, h_, w_, *_):
-        return (b_, h_, 0)
+        return (b_, h_, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), head_map),
-        pl.BlockSpec((1, 1, ps, 1, dk), page_map),
-        pl.BlockSpec((1, 1, ps, 1, dk), page_map),
-    ]
-    inputs = [q, k_pool, v_pool]
+    page_spec = pl.BlockSpec((1, 1, 1, ps, dk), page_map)
+    in_specs = [pl.BlockSpec((1, 1, n_pl, g, dk), head_map), page_spec, page_spec]
+    inputs = [qp, k_pool, v_pool]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, ps, 1, 1), page_map),
-            pl.BlockSpec((1, 1, ps, 1, 1), page_map),
-        ]
+        in_specs += [pl.BlockSpec((1, 1, hkv, ps), scale_map)] * 2
         inputs += [k_scale, v_scale]
-    in_specs += [
-        pl.BlockSpec((1, 1, dk), tok_map),
-        pl.BlockSpec((1, 1, dk), tok_map),
-    ]
-    inputs += [new_k, new_v]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, 1), tok_map),
-            pl.BlockSpec((1, 1, 1), tok_map),
-        ]
-        inputs += [new_k_scale, new_v_scale]
+    in_specs += [pl.BlockSpec((1, 1, n_pl, dk), tok_map)] * 2
+    inputs += [nk, nv]
 
     kernel = functools.partial(
         _paged_kernel,
@@ -234,18 +275,18 @@ def paged_mqa_decode_pallas(
         num_scalar_prefetch=4,
         grid=(b, hkv, n_w),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d), head_map),
+        out_specs=pl.BlockSpec((1, 1, n_pl, g, dk), head_map),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((n_pl, g, dk), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, hkv, n_pl, g, dk), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -257,20 +298,21 @@ def paged_mqa_decode_pallas(
         jnp.asarray(layer, jnp.int32).reshape(1),
         *inputs,
     )
+    return from_planes(out, axis=2).astype(q.dtype)
 
 
 def paged_mqa_decode_xla(
     q: jnp.ndarray,  # [B, Hkv, G, D]
-    k_pool: jnp.ndarray,  # [L, P, ps, Hkv, Dk]
+    k_pool: jnp.ndarray,  # [L, P, Hkv, ps, Dk]
     v_pool: jnp.ndarray,
-    k_scale,
+    k_scale,  # [L, P, Hkv, ps] f32, or None
     v_scale,
     tables: jnp.ndarray,  # [B, W] int32
     lengths: jnp.ndarray,  # [B] int32
     layer,  # scalar int32
     new_k: jnp.ndarray,  # [B, Hkv, Dk]
     new_v: jnp.ndarray,
-    new_k_scale,
+    new_k_scale,  # [B, Hkv] f32, or None
     new_v_scale,
     *,
     kv_bits: int,
@@ -282,38 +324,31 @@ def paged_mqa_decode_xla(
     so CPU walltime scales with occupied length, not table capacity (the
     kernel's per-row clamping, batch-coarsened)."""
     b, hkv, g, d = q.shape
-    n_layers, n_pages, ps = k_pool.shape[:3]
+    n_layers, n_pages, _, ps = k_pool.shape[:4]
     n_w = tables.shape[1]
     quant = kv_bits < 16
     layer = jnp.asarray(layer, jnp.int32).reshape(())
 
     # fold the layer index into the page axis so per-slot gathers never
     # materialize a whole layer's pool slice
-    kp = k_pool.reshape(n_layers * n_pages, ps, hkv, -1)
-    vp = v_pool.reshape(n_layers * n_pages, ps, hkv, -1)
+    kp = k_pool.reshape(n_layers * n_pages, hkv, ps, -1)
+    vp = v_pool.reshape(n_layers * n_pages, hkv, ps, -1)
     if quant:
-        ksp = k_scale.reshape(n_layers * n_pages, ps, hkv, 1)
-        vsp = v_scale.reshape(n_layers * n_pages, ps, hkv, 1)
+        ksp = k_scale.reshape(n_layers * n_pages, hkv, ps)
+        vsp = v_scale.reshape(n_layers * n_pages, hkv, ps)
     base = layer * n_pages
     lengths = lengths.astype(jnp.int32)
     qf = q.astype(jnp.float32)
     lo = lengths + 1 - window if window is not None else None
 
-    def dequant(page, scale):  # [B, ps, Hkv, Dk] -> [B, ps, Hkv, D] f32
-        if kv_bits == 4:
-            page = unpack_int4(page, axis=-1)
-        page = page.astype(jnp.float32)
-        if scale is not None:
-            page = page * scale.astype(jnp.float32)
-        return page
-
     def slot_step(carry, w):
         def update(carry):
             m, l, acc = carry
             pages = base + tables[:, w]  # [B]
-            kf = dequant(kp[pages], ksp[pages] if quant else None)
-            vf = dequant(vp[pages], vsp[pages] if quant else None)
-            scores = jnp.einsum("bhgd,bshd->bhgs", qf, kf) * sm_scale
+            # [B, Hkv, ps, D] f32
+            kf = dequant_kv(kp[pages], ksp[pages] if quant else None, kv_bits)
+            vf = dequant_kv(vp[pages], vsp[pages] if quant else None, kv_bits)
+            scores = jnp.einsum("bhgd,bhsd->bhgs", qf, kf) * sm_scale
             pos = w * ps + jnp.arange(ps, dtype=jnp.int32)[None, :]  # [1, ps]
             valid = pos < lengths[:, None]
             if window is not None:
@@ -324,7 +359,7 @@ def paged_mqa_decode_xla(
             alpha = jnp.exp(m - m_new)
             p = jnp.where(vmask, jnp.exp(scores - m_new), 0.0)
             l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_new = acc * alpha + jnp.einsum("bhgs,bshd->bhgd", p, vf)
+            acc_new = acc * alpha + jnp.einsum("bhgs,bhsd->bhgd", p, vf)
             return m_new, l_new, acc_new
 
         # a slot is live if ANY row has cached tokens in it that fall inside
@@ -344,12 +379,12 @@ def paged_mqa_decode_xla(
     )
 
     # fused new-token term (always valid, never read from the pool)
-    nkf = dequant(new_k[:, None], new_k_scale[:, None] if quant else None)
-    nvf = dequant(new_v[:, None], new_v_scale[:, None] if quant else None)
-    s_new = jnp.einsum("bhgd,bshd->bhgs", qf, nkf) * sm_scale  # [B,Hkv,G,1]
+    nkf = dequant_kv(new_k, new_k_scale, kv_bits)  # [B, Hkv, D]
+    nvf = dequant_kv(new_v, new_v_scale, kv_bits)
+    s_new = jnp.einsum("bhgd,bhd->bhg", qf, nkf)[..., None] * sm_scale
     m_new = jnp.maximum(m, s_new)
     alpha = jnp.exp(m - m_new)
     p = jnp.exp(s_new - m_new)
     denom = l * alpha + p
-    acc = acc * alpha + jnp.einsum("bhgs,bshd->bhgd", p, nvf)
+    acc = acc * alpha + p * nvf[:, :, None, :]
     return (acc / jnp.maximum(denom, 1e-20)).astype(q.dtype)

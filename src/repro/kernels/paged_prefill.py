@@ -12,11 +12,15 @@ chunks — and (b) the chunk itself, causally.  This is the prefill analogue of
   or wholly below the sliding-window start) to the row's nearest live page so
   they cost neither DMA nor compute — chunk attention traffic scales with the
   tokens actually cached, not table capacity.
-* int8/int4 pool payloads dequantize in-register with per-(token, head)
-  scales, exactly like the decode kernel.
-* the chunk's own K/V (computed this step, not yet in the pool) enters the
-  online softmax in the final grid step under a causal-within-chunk mask
-  (key j visible to query c iff ``j <= c``), with per-row valid lengths
+* pool pages are the decode kernel's head-major ``[ps, Dk]`` tiles; int8/int4
+  payloads dequantize in-register with per-(token, head) scale rows and
+  (even, odd) head-dim planes, exactly like the decode kernel.  Queries
+  arrive flattened to ``[C*G, Dk]`` rows (row ``r`` is chunk token
+  ``r // G``) so every block is a whole 2-D tile.
+* the chunk's own K/V (computed this step, not yet in the pool, dequantized
+  to f32 by the wrapper) enters the online softmax in the final grid step
+  under a causal-within-chunk mask (key j visible to query c iff
+  ``j <= c``), with per-row valid lengths
   ``q_lens`` masking bucket padding; the caller scatters the chunk into its
   pages afterwards.  Cached positions are all ``< ctx`` so causality against
   the pool is automatic; sliding windows mask per (query, key) distance.
@@ -35,13 +39,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.mqa_decode import _unpack_kv4
-from repro.quant.pack import unpack_int4
+from repro.kernels.paged_decode import (
+    dequant_kv,
+    dot_nn,
+    dot_nt,
+    from_planes,
+    payload_planes,
+    to_planes,
+)
 
 __all__ = ["paged_mqa_prefill_pallas", "paged_mqa_prefill_xla"]
-
-# jax < 0.5 names it TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 _NEG_INF = -1e30
 
@@ -55,10 +62,10 @@ def _prefill_kernel(
     win_ref,  # [1] int32 — window size (may be traced; 0 when has_window=False)
     layer_ref,  # [1] int32
     # blocks
-    q_ref,  # [1, 1, C, G, D]
-    k_ref,  # [1, 1, ps, 1, Dk]   (one page of one kv head)
+    q_ref,  # [1, 1, n_pl, C*G, Dk] f32
+    k_ref,  # [1, 1, 1, ps, Dk]   (one page of one kv head)
     v_ref,
-    *rest,  # [ks_ref, vs_ref,] ck_ref, cv_ref, [cks_ref, cvs_ref,] o_ref + scratch
+    *rest,  # [ks_ref, vs_ref,] ck_ref, cv_ref, o_ref + scratch
     ps: int,
     kv_bits: int,
     sm_scale: float,
@@ -69,13 +76,13 @@ def _prefill_kernel(
 ):
     quant = kv_bits < 16
     if quant:
-        ks_ref, vs_ref, ck_ref, cv_ref, cks_ref, cvs_ref = rest[:6]
-        o_ref, m_ref, l_ref, acc_ref = rest[6:]
+        ks_ref, vs_ref, ck_ref, cv_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
-        ck_ref, cv_ref = rest[:2]
-        o_ref, m_ref, l_ref, acc_ref = rest[2:]
+        ck_ref, cv_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    n_pl = q_ref.shape[2]
 
     b_idx = pl.program_id(0)
+    h_idx = pl.program_id(1)
     w_idx = pl.program_id(2)
     ctx = ctx_ref[b_idx]
 
@@ -85,7 +92,7 @@ def _prefill_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32).reshape(c * g, -1)  # [C*G, D]
+    q = [q_ref[0, 0, i] for i in range(n_pl)]  # n_pl x [C*G, Dk]
     # chunk index of each flattened query row, and its absolute position
     c_of_r = jax.lax.broadcasted_iota(jnp.int32, (c * g, 1), 0) // g  # [C*G, 1]
     q_pos = ctx + c_of_r
@@ -94,71 +101,62 @@ def _prefill_kernel(
     if has_window:
         block_live = block_live & ((w_idx + 1) * ps > win_lo_ref[b_idx])
 
-    def online_update(scores, valid, vf):
-        """One online-softmax update: scores/valid [C*G, S], vf [S, D]."""
+    def online_update(scores, valid, vp, p_scale=None):
+        """One online-softmax update: scores/valid [C*G, S], vp planes
+        [S, Dk]; ``p_scale`` is the [1, S] V-scale row (quantized pages)."""
         scores = jnp.where(valid, scores * sm_scale, _NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, vf, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        if p_scale is not None:
+            p = p * p_scale
+        for i in range(n_pl):
+            acc_ref[i] = acc_ref[i] * alpha + dot_nn(p, vp[i])
         m_ref[...] = m_new
+
+    def qk(kp):
+        scores = dot_nt(q[0], kp[0])
+        for i in range(1, n_pl):
+            scores = scores + dot_nt(q[i], kp[i])
+        return scores
 
     @pl.when(block_live)
     def _pool_update():
-        k = k_ref[0, 0, :, 0]  # [ps, Dk]
-        v = v_ref[0, 0, :, 0]
-        if kv_bits == 4:
-            k = _unpack_kv4(k)
-            v = _unpack_kv4(v)
-        kf = k.astype(jnp.float32)
-        vf = v.astype(jnp.float32)
+        scores = qk(payload_planes(k_ref[0, 0, 0], kv_bits))  # [C*G, ps]
+        vs = None
         if quant:
-            kf = kf * ks_ref[0, 0, :, 0].astype(jnp.float32)
-            vf = vf * vs_ref[0, 0, :, 0].astype(jnp.float32)
-        scores = jax.lax.dot_general(
-            q, kf, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [C*G, ps]
+            scores = scores * ks_ref[0, 0, pl.ds(h_idx, 1), :]
+            vs = vs_ref[0, 0, pl.ds(h_idx, 1), :]
         pos = w_idx * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
         valid = pos < ctx  # pool tokens all precede the chunk: causal for free
         if has_window:
             valid = valid & (q_pos - pos < win_ref[0])
-        online_update(scores, valid, vf)
+        online_update(scores, valid, payload_planes(v_ref[0, 0, 0], kv_bits), vs)
 
     @pl.when(w_idx == n_w - 1)
     def _self_chunk():
         # the chunk attends to itself causally (key j visible iff j <= c);
         # padding rows (c >= q_len) mask every key and normalize to zero.
-        ck = ck_ref[0, 0]  # [C, Dk]
-        cv = cv_ref[0, 0]
-        if kv_bits == 4:
-            ck = _unpack_kv4(ck)
-            cv = _unpack_kv4(cv)
-        ckf = ck.astype(jnp.float32)
-        cvf = cv.astype(jnp.float32)
-        if quant:
-            ckf = ckf * cks_ref[0, 0].astype(jnp.float32)
-            cvf = cvf * cvs_ref[0, 0].astype(jnp.float32)
-        scores = jax.lax.dot_general(
-            q, ckf, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [C*G, C]
+        ck = [ck_ref[0, 0, i] for i in range(n_pl)]  # [C, Dk] f32, dequantized
+        cv = [cv_ref[0, 0, i] for i in range(n_pl)]
+        scores = qk(ck)  # [C*G, C]
         j = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
         valid = (j <= c_of_r) & (j < qlen_ref[b_idx])
         if has_window:
             valid = valid & (c_of_r - j < win_ref[0])
-        online_update(scores, valid, cvf)
+        online_update(scores, valid, cv)
         denom = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / denom).reshape(c, g, -1).astype(o_ref.dtype)
+        for i in range(n_pl):
+            o_ref[0, 0, i] = acc_ref[i] / denom
 
 
 def paged_mqa_prefill_pallas(
     q: jnp.ndarray,  # [B, Hkv, C, G, D]
-    k_pool: jnp.ndarray,  # [L, P, ps, Hkv, Dk]  int8 payload or bf16
+    k_pool: jnp.ndarray,  # [L, P, Hkv, ps, Dk]  int8 payload or bf16
     v_pool: jnp.ndarray,
-    k_scale,  # [L, P, ps, Hkv, 1] f32, or None when kv_bits == 16
+    k_scale,  # [L, P, Hkv, ps] f32, or None when kv_bits == 16
     v_scale,
     tables: jnp.ndarray,  # [B, W] int32 page tables (zero-padded)
     ctx_lens: jnp.ndarray,  # [B] int32 — tokens already in the pool
@@ -166,7 +164,7 @@ def paged_mqa_prefill_pallas(
     layer: jnp.ndarray,  # [] or [1] int32 — which pool layer to read
     chunk_k: jnp.ndarray,  # [B, Hkv, C, Dk] — this chunk's K/V, not yet pooled
     chunk_v: jnp.ndarray,
-    chunk_k_scale,  # [B, Hkv, C, 1] f32, or None
+    chunk_k_scale,  # [B, Hkv, C] f32, or None
     chunk_v_scale,
     *,
     kv_bits: int,
@@ -175,51 +173,42 @@ def paged_mqa_prefill_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     b, hkv, c, g, d = q.shape
-    ps = k_pool.shape[2]
-    dk = k_pool.shape[-1]
+    ps, dk = k_pool.shape[3], k_pool.shape[4]
     n_w = tables.shape[1]
+    n_pl = d // dk
     quant = kv_bits < 16
     ctx_lens = ctx_lens.astype(jnp.int32)
     if window is not None:
         win_lo = jnp.maximum(ctx_lens + 1 - jnp.asarray(window, jnp.int32), 0)
     else:
         win_lo = jnp.zeros_like(ctx_lens)
+    qp = to_planes(
+        q.reshape(b, hkv, c * g, d).astype(jnp.float32), kv_bits, axis=2
+    )  # [B, Hkv, n_pl, C*G, Dk]
+    ck = to_planes(dequant_kv(chunk_k, chunk_k_scale, kv_bits), kv_bits, axis=2)
+    cv = to_planes(dequant_kv(chunk_v, chunk_v_scale, kv_bits), kv_bits, axis=2)
 
     def page_map(b_, h_, w_, tables_ref, ctx_ref, qlen_ref, win_lo_ref, win_ref, layer_ref):
         n_live = (ctx_ref[b_] + ps - 1) // ps
         first = win_lo_ref[b_] // ps  # 0 when no window
         slot = jnp.clip(jnp.maximum(w_, first), 0, jnp.maximum(n_live - 1, 0))
-        return (layer_ref[0], tables_ref[b_, slot], 0, h_, 0)
+        return (layer_ref[0], tables_ref[b_, slot], h_, 0, 0)
+
+    def scale_map(b_, h_, w_, *refs):
+        page = page_map(b_, h_, w_, *refs)
+        return (page[0], page[1], 0, 0)
 
     def head_map(b_, h_, w_, *_):
         return (b_, h_, 0, 0, 0)
 
-    def chunk_map(b_, h_, w_, *_):
-        return (b_, h_, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, c, g, d), head_map),
-        pl.BlockSpec((1, 1, ps, 1, dk), page_map),
-        pl.BlockSpec((1, 1, ps, 1, dk), page_map),
-    ]
-    inputs = [q, k_pool, v_pool]
+    page_spec = pl.BlockSpec((1, 1, 1, ps, dk), page_map)
+    in_specs = [pl.BlockSpec((1, 1, n_pl, c * g, dk), head_map), page_spec, page_spec]
+    inputs = [qp, k_pool, v_pool]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, ps, 1, 1), page_map),
-            pl.BlockSpec((1, 1, ps, 1, 1), page_map),
-        ]
+        in_specs += [pl.BlockSpec((1, 1, hkv, ps), scale_map)] * 2
         inputs += [k_scale, v_scale]
-    in_specs += [
-        pl.BlockSpec((1, 1, c, dk), chunk_map),
-        pl.BlockSpec((1, 1, c, dk), chunk_map),
-    ]
-    inputs += [chunk_k, chunk_v]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, c, 1), chunk_map),
-            pl.BlockSpec((1, 1, c, 1), chunk_map),
-        ]
-        inputs += [chunk_k_scale, chunk_v_scale]
+    in_specs += [pl.BlockSpec((1, 1, n_pl, c, dk), head_map)] * 2
+    inputs += [ck, cv]
 
     kernel = functools.partial(
         _prefill_kernel,
@@ -235,18 +224,18 @@ def paged_mqa_prefill_pallas(
         num_scalar_prefetch=6,
         grid=(b, hkv, n_w),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, c, g, d), head_map),
+        out_specs=pl.BlockSpec((1, 1, n_pl, c * g, dk), head_map),
         scratch_shapes=[
             pltpu.VMEM((c * g, 1), jnp.float32),
             pltpu.VMEM((c * g, 1), jnp.float32),
-            pltpu.VMEM((c * g, d), jnp.float32),
+            pltpu.VMEM((n_pl, c * g, dk), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, c, g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, hkv, n_pl, c * g, dk), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -260,13 +249,14 @@ def paged_mqa_prefill_pallas(
         jnp.asarray(layer, jnp.int32).reshape(1),
         *inputs,
     )
+    return from_planes(out, axis=2).reshape(b, hkv, c, g, d).astype(q.dtype)
 
 
 def paged_mqa_prefill_xla(
     q: jnp.ndarray,  # [B, Hkv, C, G, D]
-    k_pool: jnp.ndarray,  # [L, P, ps, Hkv, Dk]
+    k_pool: jnp.ndarray,  # [L, P, Hkv, ps, Dk]
     v_pool: jnp.ndarray,
-    k_scale,
+    k_scale,  # [L, P, Hkv, ps] f32, or None
     v_scale,
     tables: jnp.ndarray,  # [B, W] int32
     ctx_lens: jnp.ndarray,  # [B] int32
@@ -274,7 +264,7 @@ def paged_mqa_prefill_xla(
     layer,  # scalar int32
     chunk_k: jnp.ndarray,  # [B, Hkv, C, Dk]
     chunk_v: jnp.ndarray,
-    chunk_k_scale,
+    chunk_k_scale,  # [B, Hkv, C] f32, or None
     chunk_v_scale,
     *,
     kv_bits: int,
@@ -284,16 +274,16 @@ def paged_mqa_prefill_xla(
     """XLA fallback: lax.scan over page slots (lax.cond skips slots past the
     longest row), then one fused causal self-chunk softmax update."""
     b, hkv, c, g, d = q.shape
-    n_layers, n_pages, ps = k_pool.shape[:3]
+    n_layers, n_pages, _, ps = k_pool.shape[:4]
     n_w = tables.shape[1]
     quant = kv_bits < 16
     layer = jnp.asarray(layer, jnp.int32).reshape(())
 
-    kp = k_pool.reshape(n_layers * n_pages, ps, hkv, -1)
-    vp = v_pool.reshape(n_layers * n_pages, ps, hkv, -1)
+    kp = k_pool.reshape(n_layers * n_pages, hkv, ps, -1)
+    vp = v_pool.reshape(n_layers * n_pages, hkv, ps, -1)
     if quant:
-        ksp = k_scale.reshape(n_layers * n_pages, ps, hkv, 1)
-        vsp = v_scale.reshape(n_layers * n_pages, ps, hkv, 1)
+        ksp = k_scale.reshape(n_layers * n_pages, hkv, ps)
+        vsp = v_scale.reshape(n_layers * n_pages, hkv, ps)
     base = layer * n_pages
     ctx_lens = ctx_lens.astype(jnp.int32)
     q_lens = q_lens.astype(jnp.int32)
@@ -302,32 +292,24 @@ def paged_mqa_prefill_xla(
     q_pos = ctx_lens[:, None] + cpos[None, :]  # [B, C] absolute query positions
     lo = q_pos + 1 - window if window is not None else None
 
-    def dequant(page, scale):  # [B, S, Hkv, Dk] -> [B, S, Hkv, D] f32
-        if kv_bits == 4:
-            page = unpack_int4(page, axis=-1)
-        page = page.astype(jnp.float32)
-        if scale is not None:
-            page = page * scale.astype(jnp.float32)
-        return page
-
     def update(carry, kf, vf, valid):
-        """kf/vf [B, S, Hkv, D]; valid [B, C, S]."""
+        """kf/vf [B, Hkv, S, D]; valid [B, C, S]."""
         m, l, acc = carry
-        scores = jnp.einsum("bhcgd,bshd->bhcgs", qf, kf) * sm_scale
+        scores = jnp.einsum("bhcgd,bhsd->bhcgs", qf, kf) * sm_scale
         vmask = valid[:, None, :, None, :]  # [B, 1, C, 1, S]
         scores = jnp.where(vmask, scores, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.where(vmask, jnp.exp(scores - m_new), 0.0)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jnp.einsum("bhcgs,bshd->bhcgd", p, vf)
+        acc_new = acc * alpha + jnp.einsum("bhcgs,bhsd->bhcgd", p, vf)
         return m_new, l_new, acc_new
 
     def slot_step(carry, w):
         def live(carry):
             pages = base + tables[:, w]  # [B]
-            kf = dequant(kp[pages], ksp[pages] if quant else None)
-            vf = dequant(vp[pages], vsp[pages] if quant else None)
+            kf = dequant_kv(kp[pages], ksp[pages] if quant else None, kv_bits)
+            vf = dequant_kv(vp[pages], vsp[pages] if quant else None, kv_bits)
             pos = w * ps + jnp.arange(ps, dtype=jnp.int32)[None, None, :]  # [1,1,ps]
             valid = pos < ctx_lens[:, None, None]  # [B, 1, ps] -> broadcast C
             valid = jnp.broadcast_to(valid, (b, c, ps))
@@ -349,14 +331,8 @@ def paged_mqa_prefill_xla(
 
     # fused causal self-chunk term (keys are the chunk's own not-yet-pooled
     # K/V at positions ctx + j)
-    ckf = dequant(
-        chunk_k.transpose(0, 2, 1, 3),  # [B, C, Hkv, Dk]
-        chunk_k_scale.transpose(0, 2, 1, 3) if quant else None,
-    )
-    cvf = dequant(
-        chunk_v.transpose(0, 2, 1, 3),
-        chunk_v_scale.transpose(0, 2, 1, 3) if quant else None,
-    )
+    ckf = dequant_kv(chunk_k, chunk_k_scale, kv_bits)  # [B, Hkv, C, D]
+    cvf = dequant_kv(chunk_v, chunk_v_scale, kv_bits)
     j = cpos[None, None, :]  # [1, 1, C] key chunk index
     valid = (j <= cpos[None, :, None]) & (j < q_lens[:, None, None])  # [B, C, C]
     if window is not None:

@@ -9,6 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.quant.pack import unpack_int4
+
 __all__ = [
     "gather_pages",
     "mpmm_ref",
@@ -20,23 +22,18 @@ __all__ = [
 
 
 def gather_pages(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
-    """[L, P, ps, ...] paged pool + [B, W] page tables -> [L, B, W*ps, ...]
-    contiguous cache rows.
+    """[L, P, Hkv, ps, ...] head-major paged pool + [B, W] page tables ->
+    [L, B, W*ps, Hkv, ...] contiguous token-major cache rows (payloads carry
+    a trailing Dk, scales none).
 
     The gather oracle for the paged layout: the kernels index the pool in
     place, so nothing on a hot path materializes this view — tests and
     benchmarks use it to compare paged attention against the dense-cache
     oracles above.
     """
-    g = pool[:, tables]  # [L, B, W, ps, ...]
+    g = jnp.moveaxis(pool[:, tables], 3, 4)  # [L, B, W, ps, Hkv, ...]
     l, b, w, ps = g.shape[:4]
     return g.reshape(l, b, w * ps, *g.shape[4:])
-
-
-def _unpack_w4_k(packed: jnp.ndarray) -> jnp.ndarray:
-    lo = (packed << 4) >> 4
-    hi = packed >> 4
-    return jnp.stack([lo, hi], axis=1).reshape(packed.shape[0] * 2, packed.shape[1])
 
 
 def mpmm_ref(
@@ -54,7 +51,7 @@ def mpmm_ref(
     dequant mode: float matmul of x against dequantized weights, f32 accum,
     per-column scale applied.
     """
-    w = _unpack_w4_k(w_data) if w_bits == 4 else w_data
+    w = unpack_int4(w_data, axis=0) if w_bits == 4 else w_data
     if mode == "int":
         # int32 accumulation: wraparound mod 2^32, exactly the kernel's (and
         # the 32-bit SAU accumulator's) semantics.
@@ -154,14 +151,15 @@ def paged_mqa_decode_ref(
     *paged* quantized KV pool, plus the step's own (not-yet-stored) token.
 
     q:        [B, H, D]
-    k_pool:   [L, P, ps, Hkv, D]  int8 payload (pre-unpacked for kv4) or float
-    k_scale:  [L, P, ps, Hkv, 1]  f32, or None for 16-bit pools
+    k_pool:   [L, P, Hkv, ps, D]  int8 payload (pre-unpacked for kv4) or float
+    k_scale:  [L, P, Hkv, ps]     f32, or None for 16-bit pools
     tables:   [B, W] int32 — page ids, zero-padded past each row's table
     lengths:  [B] int32 — tokens already in the cache; the new token attends
               at position lengths[b], so the softmax spans lengths[b] + 1
               positions (never empty, even at lengths == 0)
     layer:    which pool layer to read
     new_k:    [B, Hkv, D] payload of this step's token (same dtype as pool)
+    new_k_scale: [B, Hkv] f32, or None
     returns:  [B, H, D] in q.dtype
 
     Semantics are gather-based on purpose: pages are collected into the
@@ -170,21 +168,21 @@ def paged_mqa_decode_ref(
     the old serve path performed, kept as the bit-reference for the kernel.
     """
     b, h, d = q.shape
-    ps, hkv = k_pool.shape[2], k_pool.shape[3]
+    hkv, ps = k_pool.shape[2], k_pool.shape[3]
     w = tables.shape[1]
     s = w * ps
     rows = jnp.arange(b)
     lengths = lengths.astype(jnp.int32)
 
     def gather(pool, scale, new, new_scale):
-        g = pool[layer][tables]  # [B, W, ps, Hkv, *]
-        g = g.reshape(b, s, *g.shape[3:]).astype(jnp.float32)
+        g = gather_pages(pool[layer][None], tables)[0]  # [B, S, Hkv, D]
+        g = g.astype(jnp.float32)
         if scale is not None:
-            sc = scale[layer][tables].reshape(b, s, hkv, 1).astype(jnp.float32)
-            g = g * sc
+            sc = gather_pages(scale[layer][None], tables)[0]  # [B, S, Hkv]
+            g = g * sc.astype(jnp.float32)[..., None]
         nf = new.astype(jnp.float32)
         if new_scale is not None:
-            nf = nf * new_scale.astype(jnp.float32)
+            nf = nf * new_scale.astype(jnp.float32)[..., None]
         # one spare position so a full table (lengths == W*ps) still has
         # room for this step's token
         g = jnp.pad(g, ((0, 0), (0, 1)) + ((0, 0),) * (g.ndim - 2))
@@ -228,13 +226,15 @@ def paged_mqa_prefill_ref(
     to a *paged* quantized KV pool plus the chunk's own (not-yet-stored) K/V.
 
     q:        [B, C, H, D]
-    k_pool:   [L, P, ps, Hkv, D]  int8 payload (pre-unpacked for kv4) or float
+    k_pool:   [L, P, Hkv, ps, D]  int8 payload (pre-unpacked for kv4) or float
+    k_scale:  [L, P, Hkv, ps]     f32, or None for 16-bit pools
     tables:   [B, W] int32 — page ids, zero-padded past each row's table
     ctx_lens: [B] int32 — tokens already materialized; chunk token c sits at
               absolute position ctx_lens[b] + c
     q_lens:   [B] int32 — valid chunk tokens per row (<= C; rest is padding
               whose output rows are unspecified garbage)
     chunk_k:  [B, C, Hkv, D] payload of this chunk (same dtype as pool)
+    chunk_k_scale: [B, C, Hkv] f32, or None
     returns:  [B, C, H, D] in q.dtype
 
     Semantics are gather-based on purpose: pages are collected into the
@@ -243,21 +243,21 @@ def paged_mqa_prefill_ref(
     computation chunked prefill must reproduce without the gather.
     """
     b, c, h, d = q.shape
-    ps, hkv = k_pool.shape[2], k_pool.shape[3]
+    hkv, ps = k_pool.shape[2], k_pool.shape[3]
     w = tables.shape[1]
     s = w * ps
     ctx_lens = ctx_lens.astype(jnp.int32)
     q_lens = q_lens.astype(jnp.int32)
 
     def gather(pool, scale, new, new_scale):
-        g = pool[layer][tables]  # [B, W, ps, Hkv, *]
-        g = g.reshape(b, s, *g.shape[3:]).astype(jnp.float32)
+        g = gather_pages(pool[layer][None], tables)[0]  # [B, S, Hkv, D]
+        g = g.astype(jnp.float32)
         if scale is not None:
-            sc = scale[layer][tables].reshape(b, s, hkv, 1).astype(jnp.float32)
-            g = g * sc
+            sc = gather_pages(scale[layer][None], tables)[0]  # [B, S, Hkv]
+            g = g * sc.astype(jnp.float32)[..., None]
         nf = new.astype(jnp.float32)
         if new_scale is not None:
-            nf = nf * new_scale.astype(jnp.float32)
+            nf = nf * new_scale.astype(jnp.float32)[..., None]
         return jnp.concatenate([g, nf], axis=1)  # [B, S + C, Hkv, D]
 
     kf = gather(k_pool, k_scale, chunk_k, chunk_k_scale)

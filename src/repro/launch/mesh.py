@@ -1,4 +1,4 @@
-"""Production mesh construction.
+"""Mesh construction: the repo's one mesh constructor and the production mesh.
 
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — the dry-run sets
@@ -7,15 +7,25 @@ and tests/benches must keep seeing 1 device.
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType, Mesh
 
 
-def make_production_mesh(*, multi_pod: bool = False):
+def make_mesh(
+    shape: Sequence[int], axes: Sequence[str], *, devices: Optional[Sequence] = None
+) -> Mesh:
+    """Every mesh in the repo comes from here.  Axes are Auto:
+    ``distributed/sharding.py`` places arrays with sharding constraints,
+    which JAX accepts only on Auto axes (``jax.make_mesh`` builds Explicit
+    ones by default)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), (AxisType.Auto,) * len(axes), devices=devices
+    )
+
+
+def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_test_mesh(data: int = 2, model: int = 2):
-    """Small mesh for subprocess multi-device tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh(shape, axes)

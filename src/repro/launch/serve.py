@@ -124,7 +124,11 @@ def main(argv: Optional[list[str]] = None) -> dict:
     if args.shared_prefix >= args.prompt_len:
         raise SystemExit("--shared-prefix must be < --prompt-len")
 
-    params = model_lib.init_params(arch, jax.random.PRNGKey(0))
+    # jitted: the f32 draws of each stacked leaf fuse into its bf16 cast
+    # instead of materializing next to it (GBs per leaf at full width)
+    params = jax.jit(model_lib.init_params, static_argnums=0)(
+        arch, jax.random.PRNGKey(0)
+    )
     max_len = args.prompt_len + args.new_tokens + arch.prefix_len + 8
     rng = np.random.default_rng(0)
     shared = rng.integers(0, arch.vocab, args.shared_prefix).astype(np.int32)
@@ -247,4 +251,7 @@ def main(argv: Optional[list[str]] = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

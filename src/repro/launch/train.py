@@ -4,27 +4,13 @@
         --steps 300 --batch 8 --seq 512 [--reduced] [--mesh-data N --mesh-model M]
 
 On a real TPU pod this binary runs per host (jax.distributed.initialize);
-here it drives the same Trainer on whatever devices exist.  Sets the XLA
-flags that let the latency-hiding scheduler overlap the per-microbatch
-gradient collectives with compute.
+here it drives the same Trainer on whatever devices exist.
 """
 from __future__ import annotations
-
-import os
-
-# Compute/comm overlap: latency-hiding scheduler + async collectives.  Must be
-# set before jax initializes.  (On TPU pods add
-# --xla_enable_async_collective_permute / --xla_tpu_enable_async_all_gather.)
-os.environ.setdefault(
-    "XLA_FLAGS",
-    "--xla_cpu_use_thunk_runtime=true",
-)
 
 import argparse
 import dataclasses
 import json
-
-import jax
 
 
 def main() -> None:
@@ -47,6 +33,7 @@ def main() -> None:
     from repro.data.pipeline import DataConfig
     from repro.distributed import sharding as sh
     from repro.distributed.fault import run_with_restarts
+    from repro.launch.mesh import make_mesh
     from repro.train import TrainConfig, Trainer
 
     arch = get_config(args.arch)
@@ -56,7 +43,7 @@ def main() -> None:
 
     mesh = None
     if args.mesh_data:
-        mesh = jax.make_mesh((args.mesh_data, args.mesh_model), ("data", "model"))
+        mesh = make_mesh((args.mesh_data, args.mesh_model), ("data", "model"))
         sh.set_mesh(mesh)
 
     tc = TrainConfig(
@@ -90,4 +77,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

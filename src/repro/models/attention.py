@@ -141,18 +141,18 @@ def decode_attention(
 
 def paged_decode_attention(
     q: jnp.ndarray,  # [B, 1, H, D]
-    k_pool: jnp.ndarray,  # [L, P, ps, Hkv, D]  (int8 payload or bf16)
+    k_pool: jnp.ndarray,  # [L, P, Hkv, ps, Dk]  (int8 payload or bf16)
     v_pool: jnp.ndarray,
     tables: jnp.ndarray,  # [B, W] int32 page tables (zero-padded)
     lengths: jnp.ndarray,  # [B] int32 — tokens already in the cache
     layer,  # int32 — which pool layer this block attends against
-    new_k: jnp.ndarray,  # [B, Hkv, D] this step's K/V (not yet in the pool)
+    new_k: jnp.ndarray,  # [B, Hkv, Dk] this step's K/V (not yet in the pool)
     new_v: jnp.ndarray,
     *,
     window: Optional[int] = None,
-    k_scale: Optional[jnp.ndarray] = None,  # [L, P, ps, Hkv, 1] when quantized
+    k_scale: Optional[jnp.ndarray] = None,  # [L, P, Hkv, ps] when quantized
     v_scale: Optional[jnp.ndarray] = None,
-    new_k_scale: Optional[jnp.ndarray] = None,  # [B, Hkv, 1]
+    new_k_scale: Optional[jnp.ndarray] = None,  # [B, Hkv]
     new_v_scale: Optional[jnp.ndarray] = None,
     kv_bits: int = 16,
 ) -> jnp.ndarray:
@@ -190,7 +190,7 @@ def paged_decode_attention(
 
 def paged_prefill_attention(
     q: jnp.ndarray,  # [B, C, H, D] — a chunk of C query tokens
-    k_pool: jnp.ndarray,  # [L, P, ps, Hkv, Dk]  (int8 payload or bf16)
+    k_pool: jnp.ndarray,  # [L, P, Hkv, ps, Dk]  (int8 payload or bf16)
     v_pool: jnp.ndarray,
     tables: jnp.ndarray,  # [B, W] int32 page tables (zero-padded)
     ctx_lens: jnp.ndarray,  # [B] int32 — tokens already in the pool
@@ -200,9 +200,9 @@ def paged_prefill_attention(
     chunk_v: jnp.ndarray,
     *,
     window: Optional[int] = None,
-    k_scale: Optional[jnp.ndarray] = None,  # [L, P, ps, Hkv, 1] when quantized
+    k_scale: Optional[jnp.ndarray] = None,  # [L, P, Hkv, ps] when quantized
     v_scale: Optional[jnp.ndarray] = None,
-    chunk_k_scale: Optional[jnp.ndarray] = None,  # [B, C, Hkv, 1]
+    chunk_k_scale: Optional[jnp.ndarray] = None,  # [B, C, Hkv]
     chunk_v_scale: Optional[jnp.ndarray] = None,
     kv_bits: int = 16,
 ) -> jnp.ndarray:

@@ -4,8 +4,9 @@ Parameters are plain nested dicts of jnp arrays.  Sharding is by name-pattern
 rules (distributed/sharding.py); activations get explicit
 with_sharding_constraint at layer boundaries.  The quantized dense layer is
 the paper's technique on the serving path: int4/int8 weights (packed, per
--output-channel scales) through kernels.ops.mpmm — Pallas on TPU, XLA dequant
-path under dry-run/CPU.
+-output-channel scales) through kernels.ops.mpmm's XLA dequant reference
+(``kernels/ref.py::mpmm_ref``) on every platform — the Pallas mpmm kernel is
+not on the model path.
 """
 from __future__ import annotations
 
@@ -46,9 +47,10 @@ def dense(x: jnp.ndarray, w: jnp.ndarray | dict) -> jnp.ndarray:
 
     Quantized weights are a dict {"data": int payload (packed along K for
     int4), "scale": [1, N] f32, "bits": StaticBits} — the paper's
-    multi-precision path.  Uses the XLA dequant route (identical numerics to
-    the Pallas kernel, which is validated separately in interpret mode and
-    substituted 1:1 on TPU).
+    multi-precision path.  Runs the XLA dequant reference
+    (``kernels/ref.py::mpmm_ref``) on every platform, TPU included: the
+    Pallas ``mpmm`` kernel is tested and compiled on its own but does not
+    serve the model.
     """
     from repro.distributed.sharding import gather_weight
 
@@ -71,7 +73,7 @@ def dense(x: jnp.ndarray, w: jnp.ndarray | dict) -> jnp.ndarray:
 def quantize_dense_weight(w: jnp.ndarray, bits: int) -> dict:
     from repro.kernels.ops import pack_weights
 
-    data, scale = pack_weights(w.astype(jnp.float32), bits)
+    data, scale = pack_weights(w, bits)
     return {"data": data, "scale": scale, "bits": StaticBits(bits)}
 
 

@@ -23,7 +23,6 @@ over layers carrying per-layer cache slices as scan xs/ys.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Optional
 
 import jax
@@ -149,23 +148,16 @@ _QUANT_KEYS = {"wq", "wk", "wv", "wo", "wg", "wu", "wd", "in_proj", "out_proj", 
 def quantize_params(params: Params, bits: int) -> Params:
     """The paper's technique on the serving path: every large matmul weight
     becomes an int4/int8 payload + per-output-channel scale.  Stacked [L, K,
-    N] weights quantize layer-wise (vmap).  Embeddings stay bf16 (gather, not
+    N] weights quantize layer-wise.  Embeddings stay bf16 (gather, not
     matmul); norms/router/ssm-vectors stay f32."""
 
-    def walk(tree, under_moe=False):
+    def walk(tree):
         out = {}
         for name, leaf in tree.items():
             if isinstance(leaf, dict):
-                out[name] = walk(leaf, under_moe or name == "moe")
+                out[name] = walk(leaf)
             elif name in _QUANT_KEYS and getattr(leaf, "ndim", 0) >= 2:
-                q = functools.partial(quantize_dense_weight, bits=bits)
-                if leaf.ndim == 2:
-                    out[name] = q(leaf)
-                else:  # stacked: [L, K, N] or moe [L, E, K, N]
-                    fn = q
-                    for _ in range(leaf.ndim - 2):
-                        fn = jax.vmap(fn)
-                    out[name] = fn(leaf)
+                out[name] = quantize_dense_weight(leaf, bits)
             else:
                 out[name] = leaf
         return out

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-__all__ = ["pack_int4", "unpack_int4", "pack_int4_hi_lo"]
+__all__ = ["pack_int4", "unpack_int4", "int4_nibbles", "pack_int4_hi_lo"]
 
 
 def pack_int4(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
@@ -28,15 +28,21 @@ def pack_int4(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     return ((hi.astype(jnp.int8) << 4) | (lo.astype(jnp.int8) & 0x0F)).astype(jnp.int8)
 
 
+def int4_nibbles(packed: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Packed int8 bytes -> (low, high) sign-extended nibbles as int32 arrays
+    of the same shape.  The shifts run in int32: the TPU kernel compiler
+    does not legalize int8 shifts, and kernels call this in-register."""
+    p = packed.astype(jnp.int32)
+    return (p << 28) >> 28, p >> 4
+
+
 def unpack_int4(packed: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     """Inverse of :func:`pack_int4`; returns int8 array of doubled length with
     sign-extended 4-bit values."""
     packed = jnp.asarray(packed, jnp.int8)
     axis = axis % packed.ndim
-    # Sign-extend low nibble: shift left then arithmetic shift right.
-    lo = (packed.astype(jnp.int8) << 4) >> 4
-    hi = packed.astype(jnp.int8) >> 4  # arithmetic shift keeps sign
-    stacked = jnp.stack([lo, hi], axis=axis + 1)  # [..., n, 2, ...]
+    lo, hi = int4_nibbles(packed)
+    stacked = jnp.stack([lo, hi], axis=axis + 1).astype(jnp.int8)  # [..., n, 2, ...]
     shape = list(packed.shape)
     shape[axis] *= 2
     return stacked.reshape(shape)

@@ -7,7 +7,7 @@ tables inside the kernel (Pallas on TPU, slot-scan XLA fallback elsewhere)
 and reads only the pages holding each row's ``lengths[b]`` cached tokens.
 The token being decoded enters the online softmax as a fused extra term, and
 after the layer scan its quantized K/V is scattered *directly* into its page
-(``pool.at[:, page, off].set``) — the old gather → insert → re-scatter
+(``pool.at[:, page, :, off].set``) — the old gather → insert → re-scatter
 round-trip through a ``[L, B, S, Hkv, D]`` view is gone, so per-token
 attention traffic is proportional to actual cache lengths, not
 ``L x B x table_capacity``.
@@ -31,6 +31,7 @@ from repro.kernels import ops
 from repro.models import attention as attn_mod
 from repro.models import transformer as model_lib
 from repro.models.layers import apply_rope, dense, rms_norm
+from repro.serve.kv_cache import scatter_tokens
 
 
 def paged_decode_step(
@@ -39,9 +40,9 @@ def paged_decode_step(
     lengths: jnp.ndarray,  # [B] int32 — tokens already in cache (new token's position)
     tables: jnp.ndarray,  # [B, W] int32 page tables (zero-padded)
     valid: jnp.ndarray,  # [B] bool — False for pow2-bucket padding rows
-    pool_k: jnp.ndarray,  # [L, P, ps, Hkv, D]
+    pool_k: jnp.ndarray,  # [L, P, Hkv, ps, Dk]
     pool_v: jnp.ndarray,
-    pool_ks,  # [L, P, ps, Hkv, 1] f32 or None (kv_bits == 16)
+    pool_ks,  # [L, P, Hkv, ps] f32 or None (kv_bits == 16)
     pool_vs,
     *,
     cfg: ArchConfig,
@@ -66,7 +67,7 @@ def paged_decode_step(
     b = tokens.shape[0]
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     n_layers = pool_k.shape[0]
-    num_pages, page_size = pool_k.shape[1], pool_k.shape[2]
+    num_pages, page_size = pool_k.shape[1], pool_k.shape[3]
     x = params["embed"].astype(jnp.dtype(cfg.dtype))[tokens]  # [B, 1, D]
     posv = lengths[:, None]  # [B, 1] per-row positions
     rows = jnp.arange(b)
@@ -84,15 +85,15 @@ def paged_decode_step(
         q = apply_rope(q, posv, cfg.rope_theta)
         k = apply_rope(k, posv, cfg.rope_theta)
         if quant:
-            kq, ksc = model_lib._quantize_token_kv(k, cfg.serve_kv_bits)
-            vq, vsc = model_lib._quantize_token_kv(v, cfg.serve_kv_bits)
+            kq, ksc = model_lib._quantize_token_kv(k[:, 0], cfg.serve_kv_bits)
+            vq, vsc = model_lib._quantize_token_kv(v[:, 0], cfg.serve_kv_bits)
+            new_kv = (kq, vq, ksc[..., 0], vsc[..., 0])  # scales [B, Hkv]
             o = attn_mod.paged_decode_attention(
-                q, pool_k, pool_v, tables, lengths, li, kq[:, 0], vq[:, 0],
+                q, pool_k, pool_v, tables, lengths, li, kq, vq,
                 window=win, k_scale=pool_ks, v_scale=pool_vs,
-                new_k_scale=ksc[:, 0], new_v_scale=vsc[:, 0],
+                new_k_scale=new_kv[2], new_v_scale=new_kv[3],
                 kv_bits=cfg.serve_kv_bits,
             )
-            new_kv = (kq[:, 0], vq[:, 0], ksc[:, 0], vsc[:, 0])
         else:
             kc = k[:, 0].astype(pool_k.dtype)
             vc = v[:, 0].astype(pool_v.dtype)
@@ -124,22 +125,10 @@ def paged_decode_step(
         mode="fill", fill_value=num_pages
     )
     page_ids = jnp.where(valid, page_ids, num_pages)
-    offs = lengths % page_size
-
-    def scatter(pool, new):
-        return pool.at[:, page_ids, offs].set(new.astype(pool.dtype), mode="drop")
-
-    if quant:
-        new_k, new_v, new_ks, new_vs = new_kv
-        pools = (
-            scatter(pool_k, new_k),
-            scatter(pool_v, new_v),
-            scatter(pool_ks, new_ks),
-            scatter(pool_vs, new_vs),
-        )
-    else:
-        new_k, new_v = new_kv
-        pools = (scatter(pool_k, new_k), scatter(pool_v, new_v), None, None)
+    pools = scatter_tokens(
+        (pool_k, pool_v, pool_ks, pool_vs), new_kv, page_ids,
+        lengths % page_size, mode="drop",
+    )
 
     x = rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
     logits = dense(x[:, -1], params["unembed"]).astype(jnp.float32)

@@ -715,7 +715,7 @@ class ServeEngine:
             if cache.quantized:
                 cache.write_prompt(
                     req.rid, kv["k"][:, i], kv["v"][:, i],
-                    kv["k_scale"][:, i], kv["v_scale"][:, i],
+                    kv["k_scale"][:, i, ..., 0], kv["v_scale"][:, i, ..., 0],
                 )
             else:
                 cache.write_prompt(req.rid, kv["k"][:, i], kv["v"][:, i])

@@ -1,11 +1,13 @@
 """Paged KV cache: a fixed pool of fixed-size pages + per-request page tables.
 
-The pool is one stacked-leading-layer-dim array per tensor — the same layout
-``models/transformer.py`` uses for its dense cache, with the contiguous
-sequence axis cut into pages:
+The pool is one stacked-leading-layer-dim array per tensor, head-major
+inside a page so that one (page, kv head) pair is a contiguous
+``[page_size, D]`` tile — the block the paged kernels DMA per grid step
+(a TPU block's two minor dimensions must be whole array dimensions or
+(8, 128)-aligned, which a per-head slice of a token-major page is not):
 
-    k_pages: [L, P, page_size, Hkv, D]       (int8 payload or bf16)
-    k_scale: [L, P, page_size, Hkv, 1]       f32, only when kv_bits < 16
+    k_pages: [L, P, Hkv, page_size, D]       (int8 payload or bf16)
+    k_scale: [L, P, Hkv, page_size]          f32, only when kv_bits < 16
 
 int4 pools pack two nibbles per byte along the head dim (the same "unified
 elements" packing the weight path uses), so the payload trailing dim is D//2.
@@ -80,11 +82,11 @@ class PagedKVCache:
             raise ValueError(f"kv4 packs nibble pairs along head_dim; hd={hd} is odd")
         payload_dtype = jnp.int8 if self.quantized else jnp.dtype(cfg.dtype)
         dk = hd // 2 if kv_bits == 4 else hd  # packed payload trailing dim
-        shape = (n_layers, num_pages, page_size, hkv, dk)
+        shape = (n_layers, num_pages, hkv, page_size, dk)
         self.k = jnp.zeros(shape, payload_dtype)
         self.v = jnp.zeros(shape, payload_dtype)
         if self.quantized:
-            sshape = (n_layers, num_pages, page_size, hkv, 1)
+            sshape = (n_layers, num_pages, hkv, page_size)
             self.k_scale = jnp.zeros(sshape, jnp.float32)
             self.v_scale = jnp.zeros(sshape, jnp.float32)
         else:
@@ -274,16 +276,17 @@ class PagedKVCache:
     def write_prompt(self, rid: int, k, v, k_scale=None, v_scale=None) -> None:
         """Scatter a prefilled contiguous cache row into this request's pages.
 
-        k/v: [L, S_pad, Hkv, Dk] with S_pad == len(table) * page_size (the
-        engine prefills with max_len rounded up to a page multiple).
+        k/v: [L, S_pad, Hkv, Dk] token-major, scales [L, S_pad, Hkv], with
+        S_pad == len(table) * page_size (the engine prefills with max_len
+        rounded up to a page multiple).
         """
         pages = jnp.asarray(self._tables[rid], jnp.int32)
         n, ps = len(self._tables[rid]), self.page_size
         if k.shape[1] != n * ps:
             raise ValueError(f"prompt cache len {k.shape[1]} != {n}*{ps}")
 
-        def scatter(pool, row):
-            paged = row.reshape(row.shape[0], n, ps, *row.shape[2:])
+        def scatter(pool, row):  # [L, n*ps, Hkv, ...] -> [L, n, Hkv, ps, ...]
+            paged = jnp.swapaxes(row.reshape(row.shape[0], n, ps, *row.shape[2:]), 2, 3)
             return pool.at[:, pages].set(paged.astype(pool.dtype))
 
         self.k = scatter(self.k, k)
@@ -296,23 +299,31 @@ class PagedKVCache:
         """Write one new token's K/V for a batch of requests.
 
         positions[i] is the cache position of request rids[i]'s new token;
-        new_kv is (k, v[, k_scale, v_scale]) with k/v [L, B, Hkv, Dk].
+        new_kv is (k, v[, k_scale, v_scale]) with k/v [L, B, Hkv, Dk] and
+        scales [L, B, Hkv].
         """
         page_ids = np.array(
             [self._tables[r][p // self.page_size] for r, p in zip(rids, positions)],
             np.int32,
         )
-        offs = jnp.asarray(positions % self.page_size, jnp.int32)
-        page_ids = jnp.asarray(page_ids)
+        self.set_pools(*scatter_tokens(
+            (self.k, self.v, self.k_scale, self.v_scale), new_kv,
+            jnp.asarray(page_ids), jnp.asarray(positions % self.page_size, jnp.int32),
+        ))
 
-        def scatter(pool, new):
-            return pool.at[:, page_ids, offs].set(new.astype(pool.dtype))
 
-        if self.quantized:
-            k, v, ks, vs = new_kv
-            self.k_scale = scatter(self.k_scale, ks)
-            self.v_scale = scatter(self.v_scale, vs)
-        else:
-            k, v = new_kv
-        self.k = scatter(self.k, k)
-        self.v = scatter(self.v, v)
+def scatter_tokens(pools, new_kv, page_ids, offs, mode=None):
+    """Scatter per-token K/V into head-major pools: ``new_kv`` leaves are
+    [L, *idx, Hkv(, Dk)] for integer index arrays ``page_ids``/``offs`` of
+    shape ``idx``; pools is (k, v, k_scale, v_scale) with None scales for
+    kv16, and so is the result.  ``mode="drop"`` discards out-of-range page
+    ids (the jitted steps' padding rows)."""
+    def scatter(pool, new):
+        # pool[:, page, :, off] puts the index dims first: [*idx, L, Hkv, ...]
+        new = jnp.moveaxis(new, 0, page_ids.ndim)
+        return pool.at[:, page_ids, :, offs].set(new.astype(pool.dtype), mode=mode)
+
+    return tuple(
+        None if pool is None else scatter(pool, new)
+        for pool, new in zip(pools, tuple(new_kv) + (None,) * (4 - len(new_kv)))
+    )

@@ -34,6 +34,7 @@ from repro.kernels import ops
 from repro.models import attention as attn_mod
 from repro.models import transformer as model_lib
 from repro.models.layers import apply_rope, dense, rms_norm
+from repro.serve.kv_cache import scatter_tokens
 
 
 def bucket_pow2(n: int) -> int:
@@ -47,9 +48,9 @@ def chunk_forward(
     q_start: jnp.ndarray,  # [B] int32 — tokens already materialized per row
     q_lens: jnp.ndarray,  # [B] int32 — valid tokens of this chunk (<= C)
     tables: jnp.ndarray,  # [B, W] int32 page tables (zero-padded)
-    pool_k: jnp.ndarray,  # [L, P, ps, Hkv, Dk]
+    pool_k: jnp.ndarray,  # [L, P, Hkv, ps, Dk]
     pool_v: jnp.ndarray,
-    pool_ks,  # [L, P, ps, Hkv, 1] f32 or None (kv_bits == 16)
+    pool_ks,  # [L, P, Hkv, ps] f32 or None (kv_bits == 16)
     pool_vs,
     *,
     cfg: ArchConfig,
@@ -84,7 +85,7 @@ def chunk_forward(
     b, c = tokens.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     n_layers = pool_k.shape[0]
-    num_pages, page_size = pool_k.shape[1], pool_k.shape[2]
+    num_pages, page_size = pool_k.shape[1], pool_k.shape[3]
     x = params["embed"].astype(jnp.dtype(cfg.dtype))[tokens]  # [B, C, D]
     q_start = q_start.astype(jnp.int32)
     q_lens = q_lens.astype(jnp.int32)
@@ -107,13 +108,13 @@ def chunk_forward(
         if quant:
             kq, ksc = model_lib._quantize_token_kv(k, cfg.serve_kv_bits)
             vq, vsc = model_lib._quantize_token_kv(v, cfg.serve_kv_bits)
+            new_kv = (kq, vq, ksc[..., 0], vsc[..., 0])  # scales [B, C, Hkv]
             o = attn_fn(
                 q, pool_k, pool_v, tables, q_start, q_lens, li, kq, vq,
                 window=win, k_scale=pool_ks, v_scale=pool_vs,
-                chunk_k_scale=ksc, chunk_v_scale=vsc,
+                chunk_k_scale=new_kv[2], chunk_v_scale=new_kv[3],
                 kv_bits=cfg.serve_kv_bits,
             )
-            new_kv = (kq, vq, ksc, vsc)
         else:
             kc = k.astype(pool_k.dtype)
             vc = v.astype(pool_v.dtype)
@@ -143,22 +144,10 @@ def chunk_forward(
         mode="fill", fill_value=num_pages
     )  # [B, C]
     page_ids = jnp.where(cpos[None, :] < q_lens[:, None], page_ids, num_pages)
-    offs = posv % page_size
-
-    def scatter(pool, new):  # new: [L, B, C, Hkv, *]
-        return pool.at[:, page_ids, offs].set(new.astype(pool.dtype), mode="drop")
-
-    if quant:
-        ck, cv, cks, cvs = new_kv
-        pools = (
-            scatter(pool_k, ck),
-            scatter(pool_v, cv),
-            scatter(pool_ks, cks),
-            scatter(pool_vs, cvs),
-        )
-    else:
-        ck, cv = new_kv
-        pools = (scatter(pool_k, ck), scatter(pool_v, cv), None, None)
+    pools = scatter_tokens(
+        (pool_k, pool_v, pool_ks, pool_vs), new_kv, page_ids,
+        posv % page_size, mode="drop",
+    )
 
     x = rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
     return x, pools
@@ -170,9 +159,9 @@ def chunk_prefill_step(
     q_start: jnp.ndarray,  # [B] int32 — tokens already materialized per row
     q_lens: jnp.ndarray,  # [B] int32 — valid tokens of this chunk (<= C)
     tables: jnp.ndarray,  # [B, W] int32 page tables (zero-padded)
-    pool_k: jnp.ndarray,  # [L, P, ps, Hkv, Dk]
+    pool_k: jnp.ndarray,  # [L, P, Hkv, ps, Dk]
     pool_v: jnp.ndarray,
-    pool_ks,  # [L, P, ps, Hkv, 1] f32 or None (kv_bits == 16)
+    pool_ks,  # [L, P, Hkv, ps] f32 or None (kv_bits == 16)
     pool_vs,
     *,
     cfg: ArchConfig,

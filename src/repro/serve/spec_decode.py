@@ -168,9 +168,9 @@ def spec_decode_round(
     valid: jnp.ndarray,  # [B] bool — False for pow2-bucket padding rows
     n_draft: jnp.ndarray,  # [B] int32 — draft tokens this row runs (<= spec_k)
     samp,  # (temperature [B], top_k [B], top_p [B], seed [B], position [B])
-    pool_k: jnp.ndarray,  # [L, P, ps, Hkv, Dk]
+    pool_k: jnp.ndarray,  # [L, P, Hkv, ps, Dk]
     pool_v: jnp.ndarray,
-    pool_ks,  # [L, P, ps, Hkv, 1] f32 or None (kv_bits == 16)
+    pool_ks,  # [L, P, Hkv, ps] f32 or None (kv_bits == 16)
     pool_vs,
     *,
     cfg: ArchConfig,

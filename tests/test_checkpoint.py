@@ -78,18 +78,19 @@ def test_elastic_reshard_subprocess(subproc):
     """Save under a (4,1) mesh, restore onto (2,2) — different topology."""
     subproc(
         """
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np, tempfile
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import CheckpointManager
 
         d = tempfile.mkdtemp()
-        mesh1 = jax.make_mesh((4, 1), ("data", "model"))
+        mesh1 = make_mesh((4, 1), ("data", "model"))
         x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
         xs = jax.device_put(x, NamedSharding(mesh1, P("data", None)))
         mgr = CheckpointManager(d)
         mgr.save(1, {"x": xs})
 
-        mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh2 = make_mesh((2, 2), ("data", "model"))
         sh2 = {"x": NamedSharding(mesh2, P("data", "model"))}
         restored, _ = mgr.restore(target={"x": x}, shardings=sh2)
         np.testing.assert_array_equal(np.asarray(restored["x"]), np.asarray(x))

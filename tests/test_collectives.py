@@ -27,12 +27,13 @@ def test_wire_bytes_are_quarter_fp32():
 def test_compressed_psum_mean_multidevice(subproc):
     subproc(
         """
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import compressed_psum_mean
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         per_shard = jnp.asarray(rng.normal(size=(8, 4096)) * 5, jnp.float32)
 
@@ -63,12 +64,13 @@ def test_error_feedback_reduces_bias(subproc):
     to the exact mean (the residual re-enters each round)."""
     subproc(
         """
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import compressed_psum_mean
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         rng = np.random.default_rng(1)
         g_all = jnp.asarray(rng.normal(size=(4, 2048)), jnp.float32)
 

@@ -15,6 +15,7 @@ import pytest
 def test_small_mesh_cell(subproc, arch, kind):
     subproc(
         f"""
+        from repro.launch.mesh import make_mesh
         import dataclasses, jax, jax.numpy as jnp
         from functools import partial
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -23,7 +24,7 @@ def test_small_mesh_cell(subproc, arch, kind):
         from repro.distributed import sharding as sh
         from repro.launch import dryrun as dr
 
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = make_mesh((4, 4), ("data", "model"))
         arch = dataclasses.replace(
             get_config("{arch}").reduced(), remat="none",
         )

@@ -17,22 +17,22 @@ RNG = np.random.default_rng(0)
 
 
 def _case(b, n_layers, n_pages, ps, w, hkv, groups, d, kv_bits):
-    """Random pool + shuffled page tables + this step's token."""
+    """Random head-major pool + shuffled page tables + this step's token."""
     h = hkv * groups
     q = jnp.asarray(RNG.normal(size=(b, h, d)), jnp.float32)
     if kv_bits < 16:
         lim = 8 if kv_bits == 4 else 128
-        kp = RNG.integers(-lim, lim, (n_layers, n_pages, ps, hkv, d)).astype(np.int8)
-        vp = RNG.integers(-lim, lim, (n_layers, n_pages, ps, hkv, d)).astype(np.int8)
-        ks = (RNG.random((n_layers, n_pages, ps, hkv, 1)) * 0.1).astype(np.float32)
-        vs = (RNG.random((n_layers, n_pages, ps, hkv, 1)) * 0.1).astype(np.float32)
+        kp = RNG.integers(-lim, lim, (n_layers, n_pages, hkv, ps, d)).astype(np.int8)
+        vp = RNG.integers(-lim, lim, (n_layers, n_pages, hkv, ps, d)).astype(np.int8)
+        ks = (RNG.random((n_layers, n_pages, hkv, ps)) * 0.1).astype(np.float32)
+        vs = (RNG.random((n_layers, n_pages, hkv, ps)) * 0.1).astype(np.float32)
         nk = RNG.integers(-lim, lim, (b, hkv, d)).astype(np.int8)
         nv = RNG.integers(-lim, lim, (b, hkv, d)).astype(np.int8)
-        nks = (RNG.random((b, hkv, 1)) * 0.1).astype(np.float32)
-        nvs = (RNG.random((b, hkv, 1)) * 0.1).astype(np.float32)
+        nks = (RNG.random((b, hkv)) * 0.1).astype(np.float32)
+        nvs = (RNG.random((b, hkv)) * 0.1).astype(np.float32)
     else:
-        kp = RNG.normal(size=(n_layers, n_pages, ps, hkv, d)).astype(np.float32)
-        vp = RNG.normal(size=(n_layers, n_pages, ps, hkv, d)).astype(np.float32)
+        kp = RNG.normal(size=(n_layers, n_pages, hkv, ps, d)).astype(np.float32)
+        vp = RNG.normal(size=(n_layers, n_pages, hkv, ps, d)).astype(np.float32)
         ks = vs = nks = nvs = None
         nk = RNG.normal(size=(b, hkv, d)).astype(np.float32)
         nv = RNG.normal(size=(b, hkv, d)).astype(np.float32)
@@ -104,15 +104,14 @@ def test_paged_matches_dense_reference():
     got = _paged(case, lengths, 0, kv_bits, "xla")
 
     rows = np.arange(b)
-    dense = lambda pool: np.asarray(pool[0])[np.asarray(tables)].reshape(
-        b, s, *pool.shape[3:]
-    )
-    kd = jnp.asarray(dense(kp)).at[rows, lengths].set(nk)
-    vd = jnp.asarray(dense(vp)).at[rows, lengths].set(nv)
-    ksd = jnp.asarray(dense(ks)).at[rows, lengths].set(nks)
-    vsd = jnp.asarray(dense(vs)).at[rows, lengths].set(nvs)
+    dense = lambda pool: ref.gather_pages(pool, tables)[0]  # [B, S, Hkv, ...]
+    kd = dense(kp).at[rows, lengths].set(nk)
+    vd = dense(vp).at[rows, lengths].set(nv)
+    ksd = dense(ks).at[rows, lengths].set(nks)
+    vsd = dense(vs).at[rows, lengths].set(nvs)
     exp = ref.mqa_decode_ref(
-        q, kd, vd, ksd, vsd, lengths + 1, sm_scale=1.0 / np.sqrt(d)
+        q, kd, vd, ksd[..., None], vsd[..., None], lengths + 1,
+        sm_scale=1.0 / np.sqrt(d),
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp), atol=3e-3, rtol=3e-3)
 
@@ -126,7 +125,7 @@ def test_zero_length_attends_only_to_new_token():
     # poison the pool: it must not leak into a zero-length row
     case = (q, kp.at[:].set(127), vp.at[:].set(127), ks, vs, tables, nk, nv, nks, nvs)
     lengths = jnp.zeros((b,), jnp.int32)
-    exp = (nv.astype(jnp.float32) * nvs).astype(np.float32)  # [B, Hkv, D]
+    exp = (nv.astype(jnp.float32) * nvs[..., None]).astype(np.float32)  # [B, Hkv, D]
     exp = np.repeat(np.asarray(exp), groups, axis=1).reshape(b, hkv * groups, d)
     for backend, interp in (("xla", None), ("pallas", True)):
         got = _paged(case, lengths, 0, 8, backend, interpret=interp)
@@ -159,7 +158,7 @@ def test_stale_pool_entries_beyond_length_are_dead():
     for row in range(b):
         ln = int(lengths[row])
         for pos in range(ln, w * ps):
-            kp2[0, int(tables[row, pos // ps]), pos % ps] = 127
+            kp2[0, int(tables[row, pos // ps]), :, pos % ps] = 127
     case2 = (q, jnp.asarray(kp2), vp, ks, vs, tables, nk, nv, nks, nvs)
     for backend, interp in (("xla", None), ("pallas", True)):
         got = _paged(case, lengths, 0, 8, backend, interpret=interp)
@@ -186,16 +185,16 @@ def test_append_then_attend_roundtrip():
     cache.allocate(0, 3)
     kq = RNG.integers(-127, 128, (L, 12, hkv, hd)).astype(np.int8)
     vq = RNG.integers(-127, 128, (L, 12, hkv, hd)).astype(np.int8)
-    ks = (RNG.random((L, 12, hkv, 1)) * 0.1).astype(np.float32)
-    vs = (RNG.random((L, 12, hkv, 1)) * 0.1).astype(np.float32)
+    ks = (RNG.random((L, 12, hkv)) * 0.1).astype(np.float32)
+    vs = (RNG.random((L, 12, hkv)) * 0.1).astype(np.float32)
     kq[:, n_tok:] = 0
     cache.write_prompt(0, jnp.asarray(kq), jnp.asarray(vq), jnp.asarray(ks), jnp.asarray(vs))
 
     q = jnp.asarray(RNG.normal(size=(1, cfg.n_heads, hd)), jnp.float32)
     nk = RNG.integers(-127, 128, (1, hkv, hd)).astype(np.int8)
     nv = RNG.integers(-127, 128, (1, hkv, hd)).astype(np.int8)
-    nks = (RNG.random((1, hkv, 1)) * 0.1).astype(np.float32)
-    nvs = (RNG.random((1, hkv, 1)) * 0.1).astype(np.float32)
+    nks = (RNG.random((1, hkv)) * 0.1).astype(np.float32)
+    nvs = (RNG.random((1, hkv)) * 0.1).astype(np.float32)
     tables = cache.table_array([0], width=3)
     lengths = jnp.asarray([n_tok], jnp.int32)
 
@@ -212,8 +211,8 @@ def test_append_then_attend_roundtrip():
     # "new token" contribution excluded by comparing against the ref oracle
     per_layer_k = np.broadcast_to(nk[None], (L, 1, hkv, hd))
     per_layer_v = np.broadcast_to(nv[None], (L, 1, hkv, hd))
-    per_layer_ks = np.broadcast_to(nks[None], (L, 1, hkv, 1))
-    per_layer_vs = np.broadcast_to(nvs[None], (L, 1, hkv, 1))
+    per_layer_ks = np.broadcast_to(nks[None], (L, 1, hkv))
+    per_layer_vs = np.broadcast_to(nvs[None], (L, 1, hkv))
     cache.write_token(
         [0], np.array([n_tok]),
         (jnp.asarray(per_layer_k), jnp.asarray(per_layer_v),
@@ -221,8 +220,8 @@ def test_append_then_attend_roundtrip():
     )
     gk = ref.gather_pages(cache.k, tables)
     gv = ref.gather_pages(cache.v, tables)
-    gks = ref.gather_pages(cache.k_scale, tables)
-    gvs = ref.gather_pages(cache.v_scale, tables)
+    gks = ref.gather_pages(cache.k_scale, tables)[..., None]
+    gvs = ref.gather_pages(cache.v_scale, tables)[..., None]
     for layer in range(L):
         stored = ref.mqa_decode_ref(
             q, gk[layer], gv[layer], gks[layer], gvs[layer],
@@ -258,9 +257,9 @@ def test_decode_step_padding_rows_leave_pool_untouched():
     after = np.asarray(pools[0])
     # row 0's token landed at page table(0)[0], offset 2
     page0 = cache.table(0)[0]
-    assert not np.array_equal(after[:, page0, 2], before[:, page0, 2])
+    assert not np.array_equal(after[:, page0, :, 2], before[:, page0, :, 2])
     # padding row wrote nowhere: pool page 0 offset 0 (its zero table entry)
-    np.testing.assert_array_equal(after[:, 0, 0], before[:, 0, 0])
+    np.testing.assert_array_equal(after[:, 0, :, 0], before[:, 0, :, 0])
 
 
 def test_mqa_decode_pallas_pads_non_multiple_widths():

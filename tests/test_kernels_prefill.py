@@ -13,22 +13,22 @@ RNG = np.random.default_rng(7)
 
 
 def _case(b, n_layers, n_pages, ps, w, c, hkv, groups, d, kv_bits):
-    """Random pool + shuffled page tables + one query chunk per row."""
+    """Random head-major pool + shuffled page tables + one query chunk per row."""
     h = hkv * groups
     q = jnp.asarray(RNG.normal(size=(b, c, h, d)), jnp.float32)
     if kv_bits < 16:
         lim = 8 if kv_bits == 4 else 128
-        kp = RNG.integers(-lim, lim, (n_layers, n_pages, ps, hkv, d)).astype(np.int8)
-        vp = RNG.integers(-lim, lim, (n_layers, n_pages, ps, hkv, d)).astype(np.int8)
-        ks = (RNG.random((n_layers, n_pages, ps, hkv, 1)) * 0.1).astype(np.float32)
-        vs = (RNG.random((n_layers, n_pages, ps, hkv, 1)) * 0.1).astype(np.float32)
+        kp = RNG.integers(-lim, lim, (n_layers, n_pages, hkv, ps, d)).astype(np.int8)
+        vp = RNG.integers(-lim, lim, (n_layers, n_pages, hkv, ps, d)).astype(np.int8)
+        ks = (RNG.random((n_layers, n_pages, hkv, ps)) * 0.1).astype(np.float32)
+        vs = (RNG.random((n_layers, n_pages, hkv, ps)) * 0.1).astype(np.float32)
         ck = RNG.integers(-lim, lim, (b, c, hkv, d)).astype(np.int8)
         cv = RNG.integers(-lim, lim, (b, c, hkv, d)).astype(np.int8)
-        cks = (RNG.random((b, c, hkv, 1)) * 0.1).astype(np.float32)
-        cvs = (RNG.random((b, c, hkv, 1)) * 0.1).astype(np.float32)
+        cks = (RNG.random((b, c, hkv)) * 0.1).astype(np.float32)
+        cvs = (RNG.random((b, c, hkv)) * 0.1).astype(np.float32)
     else:
-        kp = RNG.normal(size=(n_layers, n_pages, ps, hkv, d)).astype(np.float32)
-        vp = RNG.normal(size=(n_layers, n_pages, ps, hkv, d)).astype(np.float32)
+        kp = RNG.normal(size=(n_layers, n_pages, hkv, ps, d)).astype(np.float32)
+        vp = RNG.normal(size=(n_layers, n_pages, hkv, ps, d)).astype(np.float32)
         ks = vs = cks = cvs = None
         ck = RNG.normal(size=(b, c, hkv, d)).astype(np.float32)
         cv = RNG.normal(size=(b, c, hkv, d)).astype(np.float32)
@@ -108,9 +108,7 @@ def test_cold_chunk_is_pure_causal_self_attention():
     # and against flash attention on the dequantized chunk
     from repro.models.attention import flash_attention
 
-    ckf = jnp.repeat(ck * cks, groups, axis=2).astype(jnp.float32)
-    cvf = jnp.repeat(cv * cvs, groups, axis=2).astype(jnp.float32)
-    flash = flash_attention(q, ck * cks, cv * cvs, causal=True)
+    flash = flash_attention(q, ck * cks[..., None], cv * cvs[..., None], causal=True)
     np.testing.assert_allclose(np.asarray(exp), np.asarray(flash), atol=3e-3, rtol=3e-3)
     for backend, interp in (("xla", None), ("pallas", True)):
         got = _prefill(case, ctx, qlen, 0, 8, backend, interpret=interp)
@@ -160,7 +158,7 @@ def test_stale_pool_entries_beyond_ctx_are_dead():
     kp2 = np.asarray(kp).copy()
     for row in range(b):
         for pos in range(int(ctx[row]), w * ps):
-            kp2[0, int(tables[row, pos // ps]), pos % ps] = 127
+            kp2[0, int(tables[row, pos // ps]), :, pos % ps] = 127
     case2 = (q, jnp.asarray(kp2), vp, ks, vs, tables, ck, cv, cks, cvs)
     for backend, interp in (("xla", None), ("pallas", True)):
         got = _prefill(case, ctx, qlen, 0, 8, backend, interpret=interp)

@@ -57,11 +57,12 @@ def test_capacity_dropping_no_nans():
 def test_alltoall_matches_replicated(subproc):
     subproc(
         """
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from repro.models.moe import init_moe_params, moe_ffn
         from repro.distributed import sharding as sh
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         params = init_moe_params(jax.random.PRNGKey(0), 16, 32, 4, jnp.float32)
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(4, 8, 16)), jnp.float32)

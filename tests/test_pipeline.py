@@ -4,10 +4,11 @@
 def test_pipeline_matches_sequential(subproc):
     subproc(
         """
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import pipeline_apply
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         n_stages, n_micro, bm, d = 4, 8, 2, 16
         rng = np.random.default_rng(0)
         w = jnp.asarray(rng.normal(size=(n_stages, d, d)) * 0.3, jnp.float32)
@@ -31,10 +32,11 @@ def test_pipeline_matches_sequential(subproc):
 def test_pipeline_single_stage_degenerate(subproc):
     subproc(
         """
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import pipeline_apply
 
-        mesh = jax.make_mesh((1,), ("pod",))
+        mesh = make_mesh((1,), ("pod",))
         w = jnp.ones((1, 4, 4), jnp.float32)
         x = jnp.ones((3, 2, 4), jnp.float32)
         got = pipeline_apply(lambda p, mb: mb @ p, w, x, mesh, axis="pod")

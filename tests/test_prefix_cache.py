@@ -109,7 +109,7 @@ def test_copy_on_write_fork_leaves_original_intact():
     pool.allocate(0, 2)
     L, ps, hkv, hd = cfg.n_layers, 4, cfg.n_kv_heads, cfg.hd
     kq = rng.integers(-127, 128, (L, 8, hkv, hd)).astype(np.int8)
-    ks = (rng.random((L, 8, hkv, 1)) * 0.1).astype(np.float32)
+    ks = (rng.random((L, 8, hkv)) * 0.1).astype(np.float32)
     pool.write_prompt(0, jnp.asarray(kq), jnp.asarray(kq), jnp.asarray(ks), jnp.asarray(ks))
     orig = pool.table(0)
     # second request adopts both pages then forks the last one (divergence)
@@ -123,11 +123,11 @@ def test_copy_on_write_fork_leaves_original_intact():
     )
     # writing the fork leaves the original untouched
     tok = jnp.full((L, 1, hkv, hd), 7, jnp.int8)
-    sc = jnp.ones((L, 1, hkv, 1), jnp.float32)
+    sc = jnp.ones((L, 1, hkv), jnp.float32)
     pool.write_token([1], np.array([7]), (tok, tok, sc, sc))
-    np.testing.assert_array_equal(np.asarray(pool.k[:, orig[1], 3]), kq[:, 7])
+    np.testing.assert_array_equal(np.asarray(pool.k[:, orig[1], :, 3]), kq[:, 7])
     np.testing.assert_array_equal(
-        np.asarray(pool.k[:, new, 3]), np.full((L, hkv, hd), 7, np.int8)
+        np.asarray(pool.k[:, new, :, 3]), np.full((L, hkv, hd), 7, np.int8)
     )
 
 

@@ -110,7 +110,7 @@ def test_kv_write_gather_roundtrip():
     """Prompt scatter + per-token scatter land at the right positions."""
     cache = _tiny_cache(kv_bits=16)
     L, ps = 2, 4
-    hkv, hd = cache.k.shape[3], cache.k.shape[4]
+    hkv, hd = cache.k.shape[2], cache.k.shape[4]
     cache.allocate(7, 2)
     row = jnp.arange(L * 2 * ps * hkv * hd, dtype=jnp.float32).reshape(
         L, 2 * ps, hkv, hd
@@ -119,7 +119,7 @@ def test_kv_write_gather_roundtrip():
     tok_k = jnp.full((L, 1, hkv, hd), -1.0)
     cache.write_token([7], np.array([5]), (tok_k, tok_k))
     table = jnp.asarray(cache.table(7), jnp.int32)
-    got = cache.k[:, table].reshape(L, 2 * ps, hkv, hd)
+    got = jnp.swapaxes(cache.k[:, table], 2, 3).reshape(L, 2 * ps, hkv, hd)
     expect = row.astype(got.dtype).at[:, 5].set(-1.0)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(expect))
 
@@ -203,16 +203,16 @@ def test_paged_gather_matches_ref_oracle(setup):
     rng = np.random.default_rng(2)
     kq = rng.integers(-127, 128, (L, s, hkv, hd)).astype(np.int8)
     vq = rng.integers(-127, 128, (L, s, hkv, hd)).astype(np.int8)
-    ks = rng.random((L, s, hkv, 1)).astype(np.float32) * 0.1
-    vs = rng.random((L, s, hkv, 1)).astype(np.float32) * 0.1
+    ks = rng.random((L, s, hkv)).astype(np.float32) * 0.1
+    vs = rng.random((L, s, hkv)).astype(np.float32) * 0.1
     cache.allocate(0, 3)
     cache.write_prompt(0, jnp.asarray(kq), jnp.asarray(vq), jnp.asarray(ks), jnp.asarray(vs))
 
     tables = cache.table_array([0], width=4)  # padded wider than needed
     gk = ref.gather_pages(cache.k, tables)
     gv = ref.gather_pages(cache.v, tables)
-    gks = ref.gather_pages(cache.k_scale, tables)
-    gvs = ref.gather_pages(cache.v_scale, tables)
+    gks = ref.gather_pages(cache.k_scale, tables)[..., None]
+    gvs = ref.gather_pages(cache.v_scale, tables)[..., None]
 
     q = jnp.asarray(rng.standard_normal((1, cfg.n_heads, hd)), jnp.float32)
     lengths = jnp.asarray([10], jnp.int32)  # ragged: shorter than stored

@@ -21,11 +21,12 @@ def test_param_spec_fallback_candidates(subproc):
     shards the matrix dims instead of replicating 140 GB of experts."""
     subproc(
         """
+        from repro.launch.mesh import make_mesh
         import jax
         from jax.sharding import PartitionSpec as P
         from repro.distributed.sharding import param_spec
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         # E=8 divisible -> experts sharded
         got = param_spec("blocks/moe/wg", 4, (56, 8, 6144, 16384), mesh)
         assert got == P(None, "model", "data", None), got
@@ -41,11 +42,12 @@ def test_param_spec_fallback_candidates(subproc):
 def test_validate_spec_drops_nondivisible(subproc):
     subproc(
         """
+        from repro.launch.mesh import make_mesh
         import jax
         from jax.sharding import PartitionSpec as P
         from repro.distributed.sharding import validate_spec
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         # 7 not divisible by 2 -> dropped; 8 divisible -> kept
         got = validate_spec(P("data", "model"), (7, 8), mesh)
         assert got == P(None, "model"), got
@@ -64,13 +66,14 @@ def test_tree_shardings_cover_reduced_arch(subproc):
     mesh, and the big 2-D weights are actually model-sharded."""
     subproc(
         """
+        from repro.launch.mesh import make_mesh
         import jax
         from functools import partial
         from repro.configs import get_config
         from repro.distributed.sharding import tree_shardings
         from repro.models import transformer as T
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         for name in ("yi-9b", "kimi-k2-1t-a32b", "zamba2-7b", "mamba2-130m"):
             cfg = get_config(name).reduced()
             shapes = jax.eval_shape(partial(T.init_params, cfg), jax.random.PRNGKey(0))
