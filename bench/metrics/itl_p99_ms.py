@@ -1,0 +1,14 @@
+"""99th percentile of the gaps between consecutive output tokens of a
+request, over every gap that closes inside the window, all requests."""
+from bench.measure import percentile
+
+
+def read(run):
+    gaps = []
+    for f in run.flights:
+        ts = f.token_t
+        gaps += [b - a for a, b in zip(ts, ts[1:]) if run.t0 < b <= run.t1]
+    if not gaps:
+        return None
+    run.notes["itl_gaps"] = len(gaps)
+    return 1e3 * percentile(gaps, 99)
