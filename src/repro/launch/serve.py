@@ -212,8 +212,8 @@ def main(argv: Optional[list[str]] = None) -> dict:
         else:
             stream_events += 1
     stats = engine.stats
-    ttfts = sorted(stats.ttfts)
     outs = [outputs[r.rid] for r in reqs]
+    ttfts = sorted(o.ttft for o in outs if o.ttft is not None)
     report = {
         "arch": arch.name,
         "w_bits_mix": mix,

@@ -71,10 +71,39 @@ prefilling at step N alongside requests decoding since long before.
 Archs with frontend prefix embeddings (cfg.prefix_len > 0) keep the legacy
 one-shot-prefill path and skip the prefix cache (prefix embeddings are not
 token-addressable).
+
+**Tracing.**  ``step()`` opens host spans (``jax.profiler.TraceAnnotation``)
+at each layer boundary; they record only while the JAX profiler runs, on the
+clock of the device trace.  Nesting and args:
+
+  serve.step (step)
+    serve.admit (admitted, waiting, rids)
+    serve.prefill (groups)
+      serve.chunk (w_bits, kv_bits, rows, chunk, width, rids)
+        serve.inputs / serve.call (program) / serve.outputs
+    serve.page_room (preempted)
+    serve.decode (groups)
+      serve.decode_group (w_bits, kv_bits, rows, bsz, width, rids)
+      serve.spec_group (w_bits, kv_bits, draft_bits, rows, bsz, width, rids)
+        serve.inputs / serve.call (program) / serve.outputs
+
+``serve.inputs`` builds the host arrays of one group call, ``serve.call``
+runs the jitted program until its result is ready, ``serve.outputs`` adopts
+the pools and does the per-request bookkeeping.  A speculative group whose
+windows all shrink to one token runs, and is traced, as a plain decode
+group.  The legacy path opens
+``serve.admit`` and ``serve.prefill`` with one ``serve.call`` per prefill
+group.  The step programs are named ``serve_decode``, ``serve_chunk``,
+``serve_spec`` and ``serve_prefill`` (``program``).  ``EngineStats`` keeps,
+traced or not, the seconds inside ``serve.step`` (``step_s``),
+``serve.prefill`` (``prefill_s``), ``serve.decode`` (``decode_s``), and
+inside ``serve.call`` of decode and spec groups (``decode_wait_s``) and of
+chunks (``chunk_wait_s``); ``ServeRequest.admitted_ts`` is the moment a
+request was first admitted.
 """
 from __future__ import annotations
 
-import collections
+import contextlib
 import dataclasses
 import functools
 import time
@@ -117,40 +146,41 @@ def _make_jits(mesh):
     """Jitted engine steps closed over ``mesh`` (mesh objects aren't
     hashable jit statics, so it rides in the closure).  The four pool
     arguments of decode/chunk/spec are donated so their in-kernel K/V
-    scatters run in place — keep ``donate_argnums`` in sync with the lambda
+    scatters run in place — keep ``donate_argnums`` in sync with the
     signatures here, the single place they are spelled.  ``samp`` is the
     per-row sampling-parameter tuple (temperature, top_k, top_p, seed,
     position) every hot path now ends in: the next-token draw happens inside
-    the same jitted graph as the model step, never host-side."""
-    prefill = functools.partial(jax.jit, static_argnames=("cfg", "max_len"))(
-        lambda p, b, cfg, max_len: model_lib.prefill(p, b, cfg, max_len, mesh)
-    )
-    decode = functools.partial(
-        jax.jit, static_argnames=("cfg",), donate_argnums=(6, 7, 8, 9)
-    )(
-        lambda p, t, ln, tb, vl, samp, pk, pv, pks, pvs, cfg:
-        paged_decode_sample(
+    the same jitted graph as the model step, never host-side.  The function
+    names name the programs in a profiler trace."""
+
+    def serve_prefill(p, b, cfg, max_len):
+        return model_lib.prefill(p, b, cfg, max_len, mesh)
+
+    def serve_decode(p, t, ln, tb, vl, samp, pk, pv, pks, pvs, cfg):
+        return paged_decode_sample(
             p, t, ln, tb, vl, samp, pk, pv, pks, pvs, cfg=cfg, mesh=mesh
         )
-    )
-    chunk = functools.partial(
-        jax.jit, static_argnames=("cfg",), donate_argnums=(6, 7, 8, 9)
-    )(
-        lambda p, t, qs, ql, tb, samp, pk, pv, pks, pvs, cfg:
-        chunk_prefill_sample(
+
+    def serve_chunk(p, t, qs, ql, tb, samp, pk, pv, pks, pvs, cfg):
+        return chunk_prefill_sample(
             p, t, qs, ql, tb, samp, pk, pv, pks, pvs, cfg=cfg, mesh=mesh
         )
-    )
-    spec = functools.partial(
-        jax.jit, static_argnames=("cfg", "spec_k"), donate_argnums=(8, 9, 10, 11)
-    )(
-        lambda dp, p, t, ln, tb, vl, nd, samp, pk, pv, pks, pvs, cfg, spec_k:
-        spec_decode_round(
+
+    def serve_spec(dp, p, t, ln, tb, vl, nd, samp, pk, pv, pks, pvs, cfg, spec_k):
+        return spec_decode_round(
             dp, p, t, ln, tb, vl, nd, samp, pk, pv, pks, pvs,
             cfg=cfg, spec_k=spec_k, mesh=mesh,
         )
+
+    return (
+        jax.jit(serve_prefill, static_argnames=("cfg", "max_len")),
+        jax.jit(serve_decode, static_argnames=("cfg",), donate_argnums=(6, 7, 8, 9)),
+        jax.jit(serve_chunk, static_argnames=("cfg",), donate_argnums=(6, 7, 8, 9)),
+        jax.jit(
+            serve_spec, static_argnames=("cfg", "spec_k"),
+            donate_argnums=(8, 9, 10, 11),
+        ),
     )
-    return prefill, decode, chunk, spec
 
 
 @functools.lru_cache(maxsize=1)
@@ -160,10 +190,17 @@ def _shared_jits():
     return _make_jits(None)
 
 
+def _built(args: dict, on: bool) -> dict:
+    """A span's args as recorded: none unless tracing, callables called."""
+    if not on:
+        return {}
+    return {k: v() if callable(v) else v for k, v in args.items()}
+
+
 @dataclass
 class EngineStats:
-    prefill_s: float = 0.0
-    decode_s: float = 0.0
+    prefill_s: float = 0.0  # inside serve.prefill
+    decode_s: float = 0.0  # inside serve.decode
     decode_steps: int = 0  # batched decode kernel-group calls
     engine_steps: int = 0
     tokens_out: int = 0
@@ -179,14 +216,10 @@ class EngineStats:
     group_calls: dict = field(default_factory=dict)  # (w_bits, kv_bits) -> calls
     prefix_hit_tokens: int = 0  # prompt tokens served from cached pages
     prefix_new_tokens: int = 0  # prompt tokens actually computed
-    # latency samples for percentile reporting, bounded so a long-lived
-    # engine doesn't grow them forever (recent window is what p50/p99 mean)
-    ttfts: collections.deque = field(
-        default_factory=lambda: collections.deque(maxlen=4096)
-    )  # submit -> first token, seconds
-    decode_call_s: collections.deque = field(
-        default_factory=lambda: collections.deque(maxlen=4096)
-    )  # per decode-group call walltime, seconds
+    # host perf_counter seconds like prefill_s/decode_s (spans: module docstring)
+    step_s: float = 0.0  # inside serve.step, the whole of step()
+    decode_wait_s: float = 0.0  # serve.call of decode and spec groups
+    chunk_wait_s: float = 0.0  # serve.call of chunk-prefill calls
 
     @property
     def mean_batch_occupancy(self) -> float:
@@ -276,6 +309,23 @@ class ServeEngine:
         self.stats = EngineStats()
 
     # -------------------------------------------------------------- plumbing
+    @contextlib.contextmanager
+    def _span(self, name: str, counter: Optional[str] = None, **args):
+        """Host span ``name`` (module docstring), recorded only while the JAX
+        profiler runs.  ``counter`` names an ``EngineStats`` field that gains
+        the span's elapsed seconds, traced or not.  An arg given as a
+        callable is built only while tracing; the yielded ``note(**args)``
+        adds args known only once the work is done."""
+        on = jax.profiler.TraceAnnotation.is_enabled()
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name, **_built(args, on)) as ann:
+                yield (lambda **more: ann.set_metadata(**_built(more, on)))
+        finally:
+            if counter is not None:
+                setattr(self.stats, counter,
+                        getattr(self.stats, counter) + time.perf_counter() - t0)
+
     def params_for(self, w_bits: int):
         if w_bits not in self._params:
             self._params[w_bits] = model_lib.quantize_params(self._params[16], w_bits)
@@ -523,6 +573,23 @@ class ServeEngine:
             return True
         return False
 
+    def _admit(self, fits) -> list[ServeRequest]:
+        """FCFS admission through ``fits`` (span ``serve.admit``); stamps each
+        request's first admission (a readmission keeps it)."""
+
+        def admit(req: ServeRequest) -> bool:
+            if not fits(req):
+                return False
+            if req.admitted_ts is None:
+                req.admitted_ts = time.perf_counter()
+            return True
+
+        with self._span("serve.admit") as note:
+            admitted = self._sched.admit(admit)
+            note(admitted=len(admitted), waiting=len(self._sched.waiting),
+                 rids=lambda: [r.rid for r in admitted])
+        return admitted
+
     # ------------------------------------------------------- chunked prefill
     def _prefill_pump(self) -> None:
         """Advance every prefilling request by at most one chunk.  Requests
@@ -538,22 +605,22 @@ class ServeEngine:
         if not pumping:
             return
         pumping.sort(key=lambda r: r.arrival)
-        t0 = time.perf_counter()
-        groups: dict[tuple, list[ServeRequest]] = {}
-        for req in pumping:
-            rem = self._prefill_len(req) - req.cache_len
-            if rem <= self.prefill_chunk:
-                # clamp to the chunk budget: for non-pow2 budgets the pow2
-                # bucket could otherwise exceed the per-step token bound
-                key = (req.w_bits, req.kv_bits,
-                       min(bucket_pow2(rem), self.prefill_chunk))
-            else:  # long runners batch too: one ragged call per precision
-                key = (req.w_bits, req.kv_bits, "long")
-            groups.setdefault(key, []).append(req)
-        for key, reqs in sorted(groups.items(), key=lambda kv: kv[1][0].arrival):
-            chunk = self.prefill_chunk if key[2] == "long" else key[2]
-            self._chunk_group(reqs, chunk)
-        self.stats.prefill_s += time.perf_counter() - t0
+        with self._span("serve.prefill", "prefill_s") as note:
+            groups: dict[tuple, list[ServeRequest]] = {}
+            for req in pumping:
+                rem = self._prefill_len(req) - req.cache_len
+                if rem <= self.prefill_chunk:
+                    # clamp to the chunk budget: for non-pow2 budgets the pow2
+                    # bucket could otherwise exceed the per-step token bound
+                    key = (req.w_bits, req.kv_bits,
+                           min(bucket_pow2(rem), self.prefill_chunk))
+                else:  # long runners batch too: one ragged call per precision
+                    key = (req.w_bits, req.kv_bits, "long")
+                groups.setdefault(key, []).append(req)
+            note(groups=len(groups))
+            for key, reqs in sorted(groups.items(), key=lambda kv: kv[1][0].arrival):
+                chunk = self.prefill_chunk if key[2] == "long" else key[2]
+                self._chunk_group(reqs, chunk)
 
     def _samp_arrays(self, reqs: list[ServeRequest], bsz: int):
         """Per-row (temperature, top_k, top_p, seed, position) arrays for a
@@ -600,34 +667,45 @@ class ServeEngine:
         cfg_g = self._group_cfg(kv_bits)
         rids = [r.rid for r in reqs]
         n = len(reqs)
-        # pow2-bucket the batch dimension like decode does: padding rows have
-        # q_len 0, so they scatter nothing and their tokens are sliced off
-        bsz = bucket_pow2(n)
-        tokens = np.zeros((bsz, chunk), np.int32)
-        q_start = np.zeros(bsz, np.int32)
-        q_lens = np.zeros(bsz, np.int32)
-        for i, r in enumerate(reqs):
-            feed = r.feed_tokens()
-            q_start[i] = r.cache_len
-            q_lens[i] = min(len(feed) - r.cache_len, chunk)
-            tokens[i, : q_lens[i]] = feed[r.cache_len : r.cache_len + q_lens[i]]
-        width = max(len(cache.table(r)) for r in rids)
-        width = bucket_pow2(width)  # pow2-bucket to limit retraces
-        tables = np.zeros((bsz, width), np.int32)
-        tables[:n] = cache.table_array(rids, width)
-        first_tok, new_pools = self._chunk_fn(
-            self.params_for(w_bits), jnp.asarray(tokens), jnp.asarray(q_start),
-            jnp.asarray(q_lens), jnp.asarray(tables), self._samp_arrays(reqs, bsz),
-            cache.k, cache.v, cache.k_scale, cache.v_scale, cfg=cfg_g,
-        )
-        jax.block_until_ready(first_tok)
-        cache.set_pools(*new_pools)  # chunk K/V scattered in-kernel
-        self.stats.prefill_chunks += 1
-        first = np.asarray(first_tok)
-        for i, req in enumerate(reqs):
-            req.cache_len += int(q_lens[i])
-            if not self._prefilling(req):
-                self._on_prefill_done(req, int(first[i]))
+        with self._span("serve.chunk", w_bits=w_bits, kv_bits=kv_bits, rows=n,
+                        chunk=chunk, rids=rids) as note:
+            with self._span("serve.inputs"):
+                # pow2-bucket the batch dimension like decode does: padding
+                # rows have q_len 0, so they scatter nothing and their tokens
+                # are sliced off
+                bsz = bucket_pow2(n)
+                tokens = np.zeros((bsz, chunk), np.int32)
+                q_start = np.zeros(bsz, np.int32)
+                q_lens = np.zeros(bsz, np.int32)
+                for i, r in enumerate(reqs):
+                    feed = r.feed_tokens()
+                    q_start[i] = r.cache_len
+                    q_lens[i] = min(len(feed) - r.cache_len, chunk)
+                    tokens[i, : q_lens[i]] = feed[r.cache_len : r.cache_len + q_lens[i]]
+                width = max(len(cache.table(r)) for r in rids)
+                width = bucket_pow2(width)  # pow2-bucket to limit retraces
+                tables = np.zeros((bsz, width), np.int32)
+                tables[:n] = cache.table_array(rids, width)
+                inputs = (
+                    self.params_for(w_bits), jnp.asarray(tokens),
+                    jnp.asarray(q_start), jnp.asarray(q_lens),
+                    jnp.asarray(tables), self._samp_arrays(reqs, bsz),
+                )
+            note(width=width)
+            with self._span("serve.call", "chunk_wait_s", program="serve_chunk"):
+                first_tok, new_pools = self._chunk_fn(
+                    *inputs, cache.k, cache.v, cache.k_scale, cache.v_scale,
+                    cfg=cfg_g,
+                )
+                jax.block_until_ready(first_tok)
+            with self._span("serve.outputs"):
+                cache.set_pools(*new_pools)  # chunk K/V scattered in-kernel
+                self.stats.prefill_chunks += 1
+                first = np.asarray(first_tok)
+                for i, req in enumerate(reqs):
+                    req.cache_len += int(q_lens[i])
+                    if not self._prefilling(req):
+                        self._on_prefill_done(req, int(first[i]))
 
     def _on_prefill_done(self, req: ServeRequest, first_token: int) -> None:
         self.stats.prefills += 1
@@ -635,7 +713,6 @@ class ServeEngine:
             req.out_tokens.append(first_token)
             self.stats.tokens_out += 1
             req.ttft = time.perf_counter() - req.submit_ts
-            self.stats.ttfts.append(req.ttft)
         # register the prompt's full blocks so followers (and this request's
         # own readmission) hit them
         self._register_blocks(req)
@@ -672,17 +749,16 @@ class ServeEngine:
             reserved[req.kv_bits] = reserved.get(req.kv_bits, 0) + need
             return True
 
-        admitted = self._sched.admit(fits)
+        admitted = self._admit(fits)
         if not admitted:
             return []
         groups: dict[tuple, list[ServeRequest]] = {}
         for req in admitted:
             key = (req.w_bits, req.kv_bits, self._prefill_len(req))
             groups.setdefault(key, []).append(req)
-        t0 = time.perf_counter()
-        for (w_bits, kv_bits, plen), reqs in groups.items():
-            self._prefill_group(reqs, w_bits, kv_bits, plen)
-        self.stats.prefill_s += time.perf_counter() - t0
+        with self._span("serve.prefill", "prefill_s", groups=len(groups)):
+            for (w_bits, kv_bits, plen), reqs in groups.items():
+                self._prefill_group(reqs, w_bits, kv_bits, plen)
         return admitted
 
     def _prefill_group(self, reqs, w_bits: int, kv_bits: int, plen: int) -> None:
@@ -695,8 +771,11 @@ class ServeEngine:
             from repro.models.frontends import prefix_embeddings
 
             batch["prefix_emb"] = prefix_embeddings(self.cfg, len(reqs))
-        logits, kv = self._prefill_fn(self.params_for(w_bits), batch, cfg_g, max_len)
-        jax.block_until_ready(logits)
+        with self._span("serve.call", program="serve_prefill"):
+            logits, kv = self._prefill_fn(
+                self.params_for(w_bits), batch, cfg_g, max_len
+            )
+            jax.block_until_ready(logits)
         # legacy one-shot prefill samples on the returned logits (still a
         # jitted op — ops.sample_tokens — just not fused into the prefill)
         samp = self._samp_arrays(reqs, len(reqs))
@@ -739,22 +818,25 @@ class ServeEngine:
         Speculative requests ask for their whole verify window up front but
         *degrade to a plain-decode window* under pressure rather than evict
         anyone — speculation must never cost a batch-mate its pages."""
-        for req in sorted(self._sched.running, key=lambda r: r.arrival):
-            if req.state is not RequestState.RUNNING:
-                continue
-            cache = self.cache_for(req.kv_bits)
-            need = self._step_need(req)
-            while req.cache_len + need > cache.capacity_tokens(req.rid):
-                if cache.can_allocate(1):
-                    cache.extend(req.rid, 1)
+        before = self.stats.preemptions
+        with self._span("serve.page_room") as note:
+            for req in sorted(self._sched.running, key=lambda r: r.arrival):
+                if req.state is not RequestState.RUNNING:
                     continue
-                if need > 1:
-                    need = 1  # shrink the speculative window, keep decoding
-                    continue
-                victim = self._sched.pick_victim(kv_bits=req.kv_bits)
-                self._preempt(victim)
-                if victim is req:
-                    break
+                cache = self.cache_for(req.kv_bits)
+                need = self._step_need(req)
+                while req.cache_len + need > cache.capacity_tokens(req.rid):
+                    if cache.can_allocate(1):
+                        cache.extend(req.rid, 1)
+                        continue
+                    if need > 1:
+                        need = 1  # shrink the speculative window, keep decoding
+                        continue
+                    victim = self._sched.pick_victim(kv_bits=req.kv_bits)
+                    self._preempt(victim)
+                    if victim is req:
+                        break
+            note(preempted=self.stats.preemptions - before)
 
     def _release_pages(self, req: ServeRequest) -> None:
         """Register materialized full blocks into the prefix cache, then drop
@@ -818,13 +900,14 @@ class ServeEngine:
                     spec.setdefault(req.spec_group_key, []).append(req)
                 else:
                     plain.setdefault(req.group_key, []).append(req)
-        t0 = time.perf_counter()
-        for (w_bits, kv_bits), reqs in sorted(plain.items()):
-            self._plain_decode_group(reqs, w_bits, kv_bits)
-        for (w_bits, draft_bits, kv_bits), reqs in sorted(spec.items()):
-            self._spec_decode_group(reqs, w_bits, draft_bits, kv_bits)
-        self.stats.decode_s += time.perf_counter() - t0
         n_groups = len(plain) + len(spec)
+        if not n_groups:
+            return 0
+        with self._span("serve.decode", "decode_s", groups=n_groups):
+            for (w_bits, kv_bits), reqs in sorted(plain.items()):
+                self._plain_decode_group(reqs, w_bits, kv_bits)
+            for (w_bits, draft_bits, kv_bits), reqs in sorted(spec.items()):
+                self._spec_decode_group(reqs, w_bits, draft_bits, kv_bits)
         if n_groups >= 2:
             self.stats.mixed_precision_steps += 1
         return n_groups
@@ -836,25 +919,32 @@ class ServeEngine:
         cache = self.cache_for(kv_bits)
         cfg_g = self._group_cfg(kv_bits)
         n_real = len(reqs)
-        tokens, lengths, tables, valid = self._batch_arrays(cache, reqs)
-        t_call = time.perf_counter()
-        sampled, new_pools = self._decode_fn(
-            self.params_for(w_bits), jnp.asarray(tokens),
-            jnp.asarray(lengths), jnp.asarray(tables), jnp.asarray(valid),
-            self._samp_arrays(reqs, len(valid)),
-            cache.k, cache.v, cache.k_scale, cache.v_scale, cfg=cfg_g,
-        )
-        jax.block_until_ready(sampled)
-        self.stats.decode_call_s.append(time.perf_counter() - t_call)
-        cache.set_pools(*new_pools)  # new tokens scattered in-kernel
-        next_tok = np.asarray(sampled[:n_real])
-        for i, req in enumerate(reqs):
-            req.cache_len += 1
-            tok = int(next_tok[i])
-            req.out_tokens.append(tok)
-            self.stats.tokens_out += 1
-            if req.is_stop(tok) or len(req.out_tokens) >= req.max_new_tokens:
-                self._finish(req)
+        with self._span("serve.decode_group", w_bits=w_bits, kv_bits=kv_bits,
+                        rows=n_real, rids=lambda: [r.rid for r in reqs]) as note:
+            with self._span("serve.inputs"):
+                tokens, lengths, tables, valid = self._batch_arrays(cache, reqs)
+                inputs = (
+                    self.params_for(w_bits), jnp.asarray(tokens),
+                    jnp.asarray(lengths), jnp.asarray(tables), jnp.asarray(valid),
+                    self._samp_arrays(reqs, len(valid)),
+                )
+            note(bsz=len(valid), width=tables.shape[1])
+            with self._span("serve.call", "decode_wait_s", program="serve_decode"):
+                sampled, new_pools = self._decode_fn(
+                    *inputs, cache.k, cache.v, cache.k_scale, cache.v_scale,
+                    cfg=cfg_g,
+                )
+                jax.block_until_ready(sampled)
+            with self._span("serve.outputs"):
+                cache.set_pools(*new_pools)  # new tokens scattered in-kernel
+                next_tok = np.asarray(sampled[:n_real])
+                for i, req in enumerate(reqs):
+                    req.cache_len += 1
+                    tok = int(next_tok[i])
+                    req.out_tokens.append(tok)
+                    self.stats.tokens_out += 1
+                    if req.is_stop(tok) or len(req.out_tokens) >= req.max_new_tokens:
+                        self._finish(req)
         self.stats.decode_steps += 1
         self.stats.occupancy_sum += len(reqs)
         key = (w_bits, kv_bits)
@@ -884,43 +974,50 @@ class ServeEngine:
             self._plain_decode_group(reqs, w_bits, kv_bits)
             return
         n_real = len(reqs)
-        tokens, lengths, tables, valid = self._batch_arrays(cache, reqs)
-        nd = np.zeros(len(valid), np.int32)
-        nd[:n_real] = n_draft
-        t_call = time.perf_counter()
-        emit, accept, new_pools = self._spec_fn(
-            self.params_for(draft_bits), self.params_for(w_bits),
-            jnp.asarray(tokens), jnp.asarray(lengths), jnp.asarray(tables),
-            jnp.asarray(valid), jnp.asarray(nd),
-            self._samp_arrays(reqs, len(valid)),
-            cache.k, cache.v, cache.k_scale, cache.v_scale,
-            cfg=cfg_g, spec_k=spec_k,
-        )
-        jax.block_until_ready(emit)
-        self.stats.decode_call_s.append(time.perf_counter() - t_call)
-        cache.set_pools(*new_pools)  # draft K/V overwritten by verify K/V
-        emit_np = np.asarray(emit)
-        accept_np = np.asarray(accept)
-        for i, req in enumerate(reqs):
-            n_acc = int(accept_np[i])
-            emitted = [int(t) for t in emit_np[i, : n_acc + 1]]
-            emitted, stopped = clip_stop(req, emitted)
-            req.out_tokens.extend(emitted)
-            req.cache_len += len(emitted)
-            self.stats.tokens_out += len(emitted)
-            self.stats.spec_draft_tokens += int(n_draft[i])
-            req.spec_drafted += int(n_draft[i])
-            # count only accepted drafts the request actually used: a
-            # mid-window stop token discards the accepted tail, and an
-            # accept rate the emission didn't cash in would overstate the
-            # CI-gated metric on eos-heavy workloads
-            used_acc = min(len(emitted) - 1, n_acc)
-            self.stats.spec_accepted_tokens += used_acc
-            req.spec_accepted += used_acc
-            # rollback: drop pages holding only rejected-window positions
-            self._truncate_tail(req)
-            if stopped or len(req.out_tokens) >= req.max_new_tokens:
-                self._finish(req)
+        with self._span("serve.spec_group", w_bits=w_bits, kv_bits=kv_bits,
+                        draft_bits=draft_bits, rows=n_real,
+                        rids=lambda: [r.rid for r in reqs]) as note:
+            with self._span("serve.inputs"):
+                tokens, lengths, tables, valid = self._batch_arrays(cache, reqs)
+                nd = np.zeros(len(valid), np.int32)
+                nd[:n_real] = n_draft
+                inputs = (
+                    self.params_for(draft_bits), self.params_for(w_bits),
+                    jnp.asarray(tokens), jnp.asarray(lengths),
+                    jnp.asarray(tables), jnp.asarray(valid), jnp.asarray(nd),
+                    self._samp_arrays(reqs, len(valid)),
+                )
+            note(bsz=len(valid), width=tables.shape[1])
+            with self._span("serve.call", "decode_wait_s", program="serve_spec"):
+                emit, accept, new_pools = self._spec_fn(
+                    *inputs, cache.k, cache.v, cache.k_scale, cache.v_scale,
+                    cfg=cfg_g, spec_k=spec_k,
+                )
+                jax.block_until_ready(emit)
+            with self._span("serve.outputs"):
+                cache.set_pools(*new_pools)  # draft K/V overwritten by verify K/V
+                emit_np = np.asarray(emit)
+                accept_np = np.asarray(accept)
+                for i, req in enumerate(reqs):
+                    n_acc = int(accept_np[i])
+                    emitted = [int(t) for t in emit_np[i, : n_acc + 1]]
+                    emitted, stopped = clip_stop(req, emitted)
+                    req.out_tokens.extend(emitted)
+                    req.cache_len += len(emitted)
+                    self.stats.tokens_out += len(emitted)
+                    self.stats.spec_draft_tokens += int(n_draft[i])
+                    req.spec_drafted += int(n_draft[i])
+                    # count only accepted drafts the request actually used: a
+                    # mid-window stop token discards the accepted tail, and an
+                    # accept rate the emission didn't cash in would overstate
+                    # the CI-gated metric on eos-heavy workloads
+                    used_acc = min(len(emitted) - 1, n_acc)
+                    self.stats.spec_accepted_tokens += used_acc
+                    req.spec_accepted += used_acc
+                    # rollback: drop pages holding only rejected-window positions
+                    self._truncate_tail(req)
+                    if stopped or len(req.out_tokens) >= req.max_new_tokens:
+                        self._finish(req)
         self.stats.spec_rounds += 1
         self.stats.decode_steps += 1
         self.stats.occupancy_sum += len(reqs)
@@ -947,20 +1044,21 @@ class ServeEngine:
         """One engine iteration; returns True if any work was done (failing
         an inadmissible request counts — it empties the queue)."""
         failed_before = self.stats.failed
-        if self._legacy_prefill:
-            admitted = self._admit_and_prefill()
-            worked = bool(admitted)
-        else:
-            admitted = self._sched.admit(self._try_admit)
-            pumping = any(
-                r.state is RequestState.RUNNING and self._prefilling(r)
-                for r in self._sched.running
-            )
-            self._prefill_pump()
-            worked = bool(admitted) or pumping
-        self._ensure_page_room()
-        n_groups = self._decode_groups()
-        self.stats.engine_steps += 1
+        with self._span("serve.step", "step_s", step=self.stats.engine_steps):
+            if self._legacy_prefill:
+                admitted = self._admit_and_prefill()
+                worked = bool(admitted)
+            else:
+                admitted = self._admit(self._try_admit)
+                pumping = any(
+                    r.state is RequestState.RUNNING and self._prefilling(r)
+                    for r in self._sched.running
+                )
+                self._prefill_pump()
+                worked = bool(admitted) or pumping
+            self._ensure_page_room()
+            n_groups = self._decode_groups()
+            self.stats.engine_steps += 1
         return worked or n_groups > 0 or self.stats.failed > failed_before
 
     def run(self) -> list[ServeRequest]:

@@ -67,6 +67,7 @@ class ServeRequest:
     cache_len: int = 0  # tokens currently materialized in the KV cache
     preemptions: int = 0
     submit_ts: float = 0.0  # perf_counter at submit (TTFT reference point)
+    admitted_ts: float | None = None  # perf_counter at first admission
     ttft: float | None = None  # submit -> first output token, seconds
     error: str | None = None  # set when state is FAILED
     finish_reason: Optional[str] = None  # "stop" | "length" | "failed"
