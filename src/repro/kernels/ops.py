@@ -1,13 +1,15 @@
 """jit'd public wrappers around the Pallas kernels.
 
-These handle what the raw kernels don't: padding to block multiples, block
-size selection, FF/CF dataflow selection (via the same core.dataflow selector
-the conv mapper uses — a matmul is a 1x1 conv), weight packing/quantization,
-KV-cache quantization, and platform dispatch: the platform decides between
-compiled Pallas (TPU) and interpret mode (elsewhere).  The paged attention
-ops the serving engine calls pick the compiled kernel on a TPU and their
-slot-scan XLA fallback elsewhere.  The model's quantized projections call
-``mpmm(..., backend="xla")``, the XLA dequant reference, on every platform.
+These handle what the raw kernels don't: block size selection (and padding
+of activations to it), FF/CF dataflow selection (via the same core.dataflow
+selector the conv mapper uses — a matmul is a 1x1 conv), weight
+packing/quantization, KV-cache quantization, and platform dispatch: the
+platform decides between compiled Pallas (TPU) and interpret mode
+(elsewhere).  The paged attention ops the serving engine calls pick the
+compiled kernel on a TPU and their slot-scan XLA fallback elsewhere; the
+model's quantized projections reach :func:`mpmm` through
+``models/layers.py::dense``, which picks the Pallas kernel on a TPU with no
+mesh active and the XLA dequant reference elsewhere.
 """
 from __future__ import annotations
 
@@ -106,14 +108,46 @@ def _pad_to(x: jnp.ndarray, mult: tuple[int, ...]) -> jnp.ndarray:
     return x
 
 
-def _pick_blocks(m: int, n: int, k: int, kpack: int) -> tuple[int, int, int]:
-    def shrink(target: int, size: int, align: int) -> int:
-        b = min(target, max(align, 1 << (size - 1).bit_length()))
-        return max(align, min(b, target))
+_BM_MAX = 512  # activation rows one grid step holds
+_STEP_BYTES = 1 << 20  # weight bytes one grid step aims to DMA
+_VMEM_BUDGET = 24 << 20  # what one step's buffers and temporaries may take
 
-    bm = shrink(128, m, 8)
-    bn = shrink(128, n, 128) if n >= 128 else 128
-    bk = shrink(512, k, 128 * kpack)
+
+def _extents(size: int, align: int) -> list[int]:
+    """Block extents along a dim of ``size``: the multiples of ``align``
+    that divide it, and the whole dim (always a legal block)."""
+    return sorted({b for b in range(align, size, align) if size % b == 0} | {size})
+
+
+def _pick_blocks(
+    m: int, n: int, k: int, kpack: int, w_bytes: int = 1, x_bytes: int = 2
+) -> tuple[int, int, int]:
+    """(bm, bn, bk) for an [m, k] x [k, n] product, from the shape alone.
+
+    ``bn`` and ``bk`` divide the weight's dims — a padded weight would be a
+    full copy of it per call — so only the activation is ever padded, to a
+    multiple of ``bm``.  Among the dividing blocks, the one whose weight tile
+    comes closest to ``_STEP_BYTES`` from below wins (a grid step costs a
+    fixed ~0.35 us, which a small DMA does not hide), then the deeper K (one
+    output writeback), then the wider N.  A tile whose step would overflow
+    ``_VMEM_BUDGET`` — double-buffered operands, the in-register nibble /
+    convert temporaries, the accumulator — is passed over unless nothing
+    else fits.  Decode (m = 1..16) and chunked prefill (m up to 512) take
+    the same path; only the sizes differ.
+    """
+    bm = min(m, _BM_MAX)
+    temp = 16 if kpack == 2 else 6  # VMEM bytes of temporaries per weight byte
+
+    def key(bn: int, bk: int):
+        tile = bk // kpack * bn * w_bytes
+        vmem = 2 * bm * bk * x_bytes + (2 + temp) * tile + 12 * bm * bn
+        small = tile <= _STEP_BYTES
+        return (vmem <= _VMEM_BUDGET, small, tile if small else -tile, bk, bn)
+
+    bn, bk = max(
+        ((bn, bk) for bn in _extents(n, 128) for bk in _extents(k, 128 * kpack)),
+        key=lambda b: key(*b),
+    )
     return bm, bn, bk
 
 
@@ -125,6 +159,7 @@ def mpmm(
     x: jnp.ndarray,
     w_data: jnp.ndarray,
     w_scale: jnp.ndarray,
+    layer=None,
     *,
     w_bits: int,
     x_bits: int = 16,
@@ -135,6 +170,13 @@ def mpmm(
 ) -> jnp.ndarray:
     """Multi-precision matmul: x [..., K] @ dequant(w) -> [..., N].
 
+    ``w_data`` / ``w_scale`` are one packed weight ([K (/2), N], [1, N]) or
+    a stack of them ([L, K (/2), N], [L, 1, N]) with ``layer`` — an int32
+    scalar, traced or not — picking the one to use.  The Pallas backend
+    reads that layer in place (the index is scalar-prefetched into the
+    kernel's block index maps); the XLA backend slices it out and runs the
+    oracle, ``kernels/ref.py::mpmm_ref``.
+
     int mode returns f32 = int32_acc * w_scale (int arithmetic inside);
     dequant mode returns x.dtype.
     """
@@ -142,12 +184,18 @@ def mpmm(
         interpret = _interpret_default()
     if mode == "dequant" and w_bits == 16:
         raise ValueError("w16 requires int mode (bf16 cannot hold int16 exactly)")
+    if w_data.ndim == 3 and layer is None:
+        raise ValueError("a stacked [L, K, N] weight needs its layer index")
     lead = x.shape[:-1]
     k_sz = x.shape[-1]
     kpack = 2 if w_bits == 4 else 1
     n_sz = w_data.shape[-1]
     x2 = x.reshape(-1, k_sz)
     m_sz = x2.shape[0]
+    pick = lambda a: a if w_data.ndim == 2 else jax.lax.dynamic_index_in_dim(
+        a, layer, keepdims=False
+    )
+    scale = pick(w_scale)
 
     if dataflow == "auto":
         dataflow = (
@@ -155,19 +203,19 @@ def mpmm(
         )
 
     if backend == "xla":
-        out = ref_mod.mpmm_ref(x2, w_data, w_scale, w_bits=w_bits, mode=mode)
+        out = ref_mod.mpmm_ref(x2, pick(w_data), scale, w_bits=w_bits, mode=mode)
         if mode == "int":
-            out = out.astype(jnp.float32) * w_scale.astype(jnp.float32)
+            out = out.astype(jnp.float32) * scale.astype(jnp.float32)
         return out.reshape(*lead, n_sz)
 
-    bm, bn, bk = _pick_blocks(m_sz, n_sz, k_sz, kpack)
-    xp = _pad_to(x2, (bm, bk))
-    wp = _pad_to(w_data, (bk // kpack, bn))
-    sp = _pad_to(w_scale.reshape(1, -1), (1, bn))
+    bm, bn, bk = _pick_blocks(
+        m_sz, n_sz, k_sz, kpack, w_data.dtype.itemsize, x2.dtype.itemsize
+    )
     out = mpmm_mod.mpmm_pallas(
-        xp,
-        wp,
-        sp,
+        _pad_to(x2, (bm, 1)),
+        w_data,
+        w_scale,
+        layer,
         w_bits=w_bits,
         x_bits=x_bits,
         mode=mode,
@@ -176,15 +224,14 @@ def mpmm(
         bn=bn,
         bk=bk,
         interpret=interpret,
-    )
-    out = out[:m_sz, :n_sz]
+    )[:m_sz]
     if mode == "int":
-        out = out.astype(jnp.float32) * w_scale.astype(jnp.float32)
+        out = out.astype(jnp.float32) * scale.astype(jnp.float32)
     elif dataflow == "ff":
         # FF dequant partials arrive as f32 (the kernel's cross-stage
         # accumulator); apply the scale in f32 like the CF kernel does
         # in-VMEM, then cast once to the activation dtype
-        out = (out * w_scale.astype(jnp.float32)).astype(x.dtype)
+        out = (out * scale.astype(jnp.float32)).astype(x.dtype)
     return out.reshape(*lead, n_sz)
 
 

@@ -4,9 +4,12 @@ Parameters are plain nested dicts of jnp arrays.  Sharding is by name-pattern
 rules (distributed/sharding.py); activations get explicit
 with_sharding_constraint at layer boundaries.  The quantized dense layer is
 the paper's technique on the serving path: int4/int8 weights (packed, per
--output-channel scales) through kernels.ops.mpmm's XLA dequant reference
-(``kernels/ref.py::mpmm_ref``) on every platform — the Pallas mpmm kernel is
-not on the model path.
+-output-channel scales) through ``kernels.ops.mpmm`` — the Pallas ``mpmm``
+kernel on a TPU with no mesh active, the XLA dequant reference
+(``kernels/ref.py::mpmm_ref``) elsewhere.  Layer scans hand ``dense`` the
+whole stack of a quantized weight and the layer index
+(:func:`split_stacked`, :func:`layer_view`), so the kernel reads each layer's
+packed bytes where they lie.
 """
 from __future__ import annotations
 
@@ -42,20 +45,36 @@ class StaticBits(int):
     """Quantization bit-width carried in the treedef (static, never traced)."""
 
 
+def mpmm_backend() -> str:
+    """Where ``dense`` runs quantized weights: ``"pallas"`` (the ``mpmm``
+    kernel) on a TPU with no mesh active, ``"xla"`` (the dequant reference)
+    otherwise — a sharded weight cannot enter an unpartitioned custom call,
+    and elsewhere than a TPU the reference is the oracle."""
+    from repro.distributed import sharding
+
+    if jax.default_backend() == "tpu" and sharding.get_mesh() is None:
+        return "pallas"
+    return "xla"
+
+
+def _is_quantized(w) -> bool:
+    return isinstance(w, dict) and "bits" in w
+
+
 def dense(x: jnp.ndarray, w: jnp.ndarray | dict) -> jnp.ndarray:
     """Dense matmul dispatching on plain vs quantized weights.
 
     Quantized weights are a dict {"data": int payload (packed along K for
     int4), "scale": [1, N] f32, "bits": StaticBits} — the paper's
-    multi-precision path.  Runs the XLA dequant reference
-    (``kernels/ref.py::mpmm_ref``) on every platform, TPU included: the
-    Pallas ``mpmm`` kernel is tested and compiled on its own but does not
-    serve the model.
+    multi-precision path — run through ``kernels.ops.mpmm`` on the backend
+    :func:`mpmm_backend` picks.  Inside a layer scan the dict holds the
+    whole stack ({"data": [L, K (/2), N], "scale": [L, 1, N]}) and a
+    ``"layer"`` index (:func:`layer_view`).
     """
     from repro.distributed.sharding import gather_weight
 
     w = gather_weight(w)
-    if isinstance(w, dict):  # quantized
+    if _is_quantized(w):
         from repro.kernels.ops import mpmm
 
         bits = int(w["bits"])
@@ -63,11 +82,42 @@ def dense(x: jnp.ndarray, w: jnp.ndarray | dict) -> jnp.ndarray:
             x,
             w["data"],
             w["scale"],
+            w.get("layer"),
             w_bits=bits,
             mode="dequant" if bits < 16 else "int",
-            backend="xla",
+            backend=mpmm_backend(),
         ).astype(x.dtype)
     return jnp.einsum("...k,kn->...n", x, w.astype(x.dtype))
+
+
+def split_stacked(blocks: Params) -> tuple[Params, Params]:
+    """Split a stacked [L, ...] block tree for a layer scan: ``(xs,
+    stacked)``.  Quantized projections (``{"data": [L, K (/2), N], ...}``)
+    leave ``xs`` — which the scan slices, one copy per layer — and stay
+    whole in ``stacked``, which the scan body closes over; everything else
+    (norms, plain weights, expert stacks) stays in ``xs``."""
+    xs, stacked = {}, {}
+    for name, leaf in blocks.items():
+        if _is_quantized(leaf) and leaf["data"].ndim == 3:
+            stacked[name] = leaf
+        elif isinstance(leaf, dict) and not _is_quantized(leaf):
+            xs[name], stacked[name] = split_stacked(leaf)
+        else:
+            xs[name] = leaf
+    return xs, stacked
+
+
+def layer_view(p: Params, stacked: Params, li) -> Params:
+    """Layer ``li``'s block tree inside the scan body: the scan's slice
+    ``p`` with each stacked quantized projection put back, whole, beside
+    the layer index ``dense`` hands to the kernel."""
+    out = dict(p)
+    for name, leaf in stacked.items():
+        if _is_quantized(leaf):
+            out[name] = {**leaf, "layer": li}
+        else:
+            out[name] = layer_view(p[name], leaf, li)
+    return out
 
 
 def quantize_dense_weight(w: jnp.ndarray, bits: int) -> dict:

@@ -15,7 +15,9 @@ attention traffic is proportional to actual cache lengths, not
 All weight matmuls go through ``models.layers.dense``, which dispatches
 quantized weights to the ``mpmm`` multi-precision kernel path, so a W4A16
 group and a W8A16 group each cost one batched kernel call per projection per
-layer.  Unlike ``models.transformer.decode_step`` (one shared scalar
+layer; the layer scan hands it each quantized projection's whole ``[L, ...]``
+stack and the layer index, so the kernel reads that layer's packed bytes in
+place.  Unlike ``models.transformer.decode_step`` (one shared scalar
 position), every row carries its own cache length — requests that joined the
 batch at different times decode together.  Rows with ``valid[b] == False``
 are pow2-bucket padding: they compute garbage logits (sliced off by the
@@ -30,7 +32,7 @@ from repro.configs.base import ArchConfig
 from repro.kernels import ops
 from repro.models import attention as attn_mod
 from repro.models import transformer as model_lib
-from repro.models.layers import apply_rope, dense, rms_norm
+from repro.models.layers import apply_rope, dense, layer_view, rms_norm, split_stacked
 from repro.serve.kv_cache import scatter_tokens
 
 
@@ -76,7 +78,8 @@ def paged_decode_step(
 
     def layer(carry, xs):
         x = carry
-        p, li = xs["p"], xs["li"]
+        li = xs["li"]
+        p = layer_view(xs["p"], stacked, li)
         win = xs["win"] if windows is not None else (cfg.window if cfg.window else None)
         xn = rms_norm(x, p["norm1"].astype(x.dtype), cfg.norm_eps)
         q = dense(xn, p["wq"]).reshape(b, 1, h, hd)
@@ -110,7 +113,9 @@ def paged_decode_step(
             x = x + model_lib._mlp_block(p, x, cfg)
         return x, new_kv
 
-    xs = {"p": params["blocks"], "li": jnp.arange(n_layers, dtype=jnp.int32)}
+    # quantized projections stay stacked: the kernel reads layer li in place
+    per_layer, stacked = split_stacked(params["blocks"])
+    xs = {"p": per_layer, "li": jnp.arange(n_layers, dtype=jnp.int32)}
     if windows is not None:
         xs["win"] = windows
     x, new_kv = jax.lax.scan(layer, x, xs)

@@ -80,12 +80,12 @@ clock of the device trace.  Nesting and args:
     serve.admit (admitted, waiting, rids)
     serve.prefill (groups)
       serve.chunk (w_bits, kv_bits, rows, chunk, width, rids)
-        serve.inputs / serve.call (program) / serve.outputs
+        serve.inputs / serve.call (program, mpmm) / serve.outputs
     serve.page_room (preempted)
     serve.decode (groups)
       serve.decode_group (w_bits, kv_bits, rows, bsz, width, rids)
       serve.spec_group (w_bits, kv_bits, draft_bits, rows, bsz, width, rids)
-        serve.inputs / serve.call (program) / serve.outputs
+        serve.inputs / serve.call (program, mpmm) / serve.outputs
 
 ``serve.inputs`` builds the host arrays of one group call, ``serve.call``
 runs the jitted program until its result is ready, ``serve.outputs`` adopts
@@ -94,12 +94,16 @@ windows all shrink to one token runs, and is traced, as a plain decode
 group.  The legacy path opens
 ``serve.admit`` and ``serve.prefill`` with one ``serve.call`` per prefill
 group.  The step programs are named ``serve_decode``, ``serve_chunk``,
-``serve_spec`` and ``serve_prefill`` (``program``).  ``EngineStats`` keeps,
+``serve_spec`` and ``serve_prefill`` (``program``); ``mpmm`` says what the
+program's quantized projections run on: ``"pallas"`` (the mpmm kernel),
+``"xla"`` (the dequant reference) or ``"none"`` (bf16 weights only; see
+``models.layers.mpmm_backend``).  ``EngineStats`` keeps,
 traced or not, the seconds inside ``serve.step`` (``step_s``),
 ``serve.prefill`` (``prefill_s``), ``serve.decode`` (``decode_s``), and
 inside ``serve.call`` of decode and spec groups (``decode_wait_s``) and of
-chunks (``chunk_wait_s``); ``ServeRequest.admitted_ts`` is the moment a
-request was first admitted.
+chunks (``chunk_wait_s``), and counts the decode, spec and chunk calls that
+ran the mpmm kernel (``mpmm_kernel_calls``); ``ServeRequest.admitted_ts`` is
+the moment a request was first admitted.
 """
 from __future__ import annotations
 
@@ -117,6 +121,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.kernels import ops
+from repro.models import layers
 from repro.models import transformer as model_lib
 from repro.serve.decode import paged_decode_sample
 from repro.serve.kv_cache import PagedKVCache
@@ -220,6 +225,7 @@ class EngineStats:
     step_s: float = 0.0  # inside serve.step, the whole of step()
     decode_wait_s: float = 0.0  # serve.call of decode and spec groups
     chunk_wait_s: float = 0.0  # serve.call of chunk-prefill calls
+    mpmm_kernel_calls: int = 0  # decode/spec/chunk calls with mpmm="pallas"
 
     @property
     def mean_batch_occupancy(self) -> float:
@@ -325,6 +331,16 @@ class ServeEngine:
             if counter is not None:
                 setattr(self.stats, counter,
                         getattr(self.stats, counter) + time.perf_counter() - t0)
+
+    @staticmethod
+    def _mpmm_path(*w_bits: int) -> str:
+        """What the quantized projections of a program over these weight
+        sets run on — ``"pallas"`` (the mpmm kernel) or ``"xla"``, as
+        ``models.layers.mpmm_backend`` decides — or ``"none"`` when every set
+        is plain bf16.  Static per program, so known at dispatch."""
+        if all(b >= 16 for b in w_bits):
+            return "none"
+        return layers.mpmm_backend()
 
     def params_for(self, w_bits: int):
         if w_bits not in self._params:
@@ -692,7 +708,9 @@ class ServeEngine:
                     jnp.asarray(tables), self._samp_arrays(reqs, bsz),
                 )
             note(width=width)
-            with self._span("serve.call", "chunk_wait_s", program="serve_chunk"):
+            mpmm = self._mpmm_path(w_bits)
+            with self._span("serve.call", "chunk_wait_s", program="serve_chunk",
+                            mpmm=mpmm):
                 first_tok, new_pools = self._chunk_fn(
                     *inputs, cache.k, cache.v, cache.k_scale, cache.v_scale,
                     cfg=cfg_g,
@@ -701,6 +719,7 @@ class ServeEngine:
             with self._span("serve.outputs"):
                 cache.set_pools(*new_pools)  # chunk K/V scattered in-kernel
                 self.stats.prefill_chunks += 1
+                self.stats.mpmm_kernel_calls += mpmm == "pallas"
                 first = np.asarray(first_tok)
                 for i, req in enumerate(reqs):
                     req.cache_len += int(q_lens[i])
@@ -771,7 +790,8 @@ class ServeEngine:
             from repro.models.frontends import prefix_embeddings
 
             batch["prefix_emb"] = prefix_embeddings(self.cfg, len(reqs))
-        with self._span("serve.call", program="serve_prefill"):
+        with self._span("serve.call", program="serve_prefill",
+                        mpmm=self._mpmm_path(w_bits)):
             logits, kv = self._prefill_fn(
                 self.params_for(w_bits), batch, cfg_g, max_len
             )
@@ -929,7 +949,9 @@ class ServeEngine:
                     self._samp_arrays(reqs, len(valid)),
                 )
             note(bsz=len(valid), width=tables.shape[1])
-            with self._span("serve.call", "decode_wait_s", program="serve_decode"):
+            mpmm = self._mpmm_path(w_bits)
+            with self._span("serve.call", "decode_wait_s", program="serve_decode",
+                            mpmm=mpmm):
                 sampled, new_pools = self._decode_fn(
                     *inputs, cache.k, cache.v, cache.k_scale, cache.v_scale,
                     cfg=cfg_g,
@@ -946,6 +968,7 @@ class ServeEngine:
                     if req.is_stop(tok) or len(req.out_tokens) >= req.max_new_tokens:
                         self._finish(req)
         self.stats.decode_steps += 1
+        self.stats.mpmm_kernel_calls += mpmm == "pallas"
         self.stats.occupancy_sum += len(reqs)
         key = (w_bits, kv_bits)
         self.stats.group_calls[key] = self.stats.group_calls.get(key, 0) + 1
@@ -988,7 +1011,9 @@ class ServeEngine:
                     self._samp_arrays(reqs, len(valid)),
                 )
             note(bsz=len(valid), width=tables.shape[1])
-            with self._span("serve.call", "decode_wait_s", program="serve_spec"):
+            mpmm = self._mpmm_path(w_bits, draft_bits)
+            with self._span("serve.call", "decode_wait_s", program="serve_spec",
+                            mpmm=mpmm):
                 emit, accept, new_pools = self._spec_fn(
                     *inputs, cache.k, cache.v, cache.k_scale, cache.v_scale,
                     cfg=cfg_g, spec_k=spec_k,
@@ -1020,6 +1045,7 @@ class ServeEngine:
                         self._finish(req)
         self.stats.spec_rounds += 1
         self.stats.decode_steps += 1
+        self.stats.mpmm_kernel_calls += mpmm == "pallas"
         self.stats.occupancy_sum += len(reqs)
         key = (w_bits, kv_bits)
         self.stats.group_calls[key] = self.stats.group_calls.get(key, 0) + 1

@@ -33,7 +33,7 @@ from repro.configs.base import ArchConfig
 from repro.kernels import ops
 from repro.models import attention as attn_mod
 from repro.models import transformer as model_lib
-from repro.models.layers import apply_rope, dense, rms_norm
+from repro.models.layers import apply_rope, dense, layer_view, rms_norm, split_stacked
 from repro.serve.kv_cache import scatter_tokens
 
 
@@ -97,7 +97,8 @@ def chunk_forward(
 
     def layer(carry, xs):
         x = carry
-        p, li = xs["p"], xs["li"]
+        li = xs["li"]
+        p = layer_view(xs["p"], stacked, li)
         win = xs["win"] if windows is not None else (cfg.window if cfg.window else None)
         xn = rms_norm(x, p["norm1"].astype(x.dtype), cfg.norm_eps)
         q = dense(xn, p["wq"]).reshape(b, c, h, hd)
@@ -131,7 +132,9 @@ def chunk_forward(
             x = x + model_lib._mlp_block(p, x, cfg)
         return x, new_kv
 
-    xs = {"p": params["blocks"], "li": jnp.arange(n_layers, dtype=jnp.int32)}
+    # quantized projections stay stacked: the kernel reads layer li in place
+    per_layer, stacked = split_stacked(params["blocks"])
+    xs = {"p": per_layer, "li": jnp.arange(n_layers, dtype=jnp.int32)}
     if windows is not None:
         xs["win"] = windows
     x, new_kv = jax.lax.scan(layer, x, xs)

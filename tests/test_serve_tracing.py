@@ -13,6 +13,8 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.configs import get_config
+from repro.kernels import mpmm as mpmm_mod
+from repro.models import layers
 from repro.models import transformer as T
 from repro.serve import PrecisionParams, SamplingParams, ServeEngine
 from repro.serve.engine import _shared_jits
@@ -28,7 +30,7 @@ ARGS = {
     "serve.decode_group": GROUP_ARGS,
     "serve.spec_group": GROUP_ARGS | {"draft_bits"},
     "serve.inputs": set(),
-    "serve.call": {"program"},
+    "serve.call": {"program", "mpmm"},
     "serve.outputs": set(),
 }
 GROUPS = ("serve.chunk", "serve.decode_group", "serve.spec_group")
@@ -177,6 +179,28 @@ def test_dispatches_carry_the_program_names(traced_mix):
     assert names == ["serve_prefill", "serve_decode", "serve_chunk", "serve_spec"]
 
 
+def _call_paths(events):
+    """(w_bits, draft_bits, mpmm) of each serve.call, from its group span."""
+    spans = [e for e in events if e[2].startswith("serve.")]
+    out = []
+    for call in (e for e in spans if e[2] == "serve.call"):
+        group = _parent(call, spans)
+        out.append((group[3]["w_bits"], group[3].get("draft_bits"), call[3]["mpmm"]))
+    return out
+
+
+def test_serve_call_names_what_the_projections_run_on(traced_mix):
+    """Off a TPU the quantized projections run the XLA reference, bf16 ones
+    nothing of mpmm, and the kernel counter stays at zero."""
+    eng, _, _, events = traced_mix
+    paths = _call_paths(events)
+    assert {p for _, _, p in paths} == {"xla", "none"}
+    for w_bits, draft_bits, path in paths:
+        quantized = w_bits < 16 or (draft_bits or 16) < 16
+        assert path == ("xla" if quantized else "none")
+    assert eng.stats.mpmm_kernel_calls == 0
+
+
 def test_counters_split_the_step_into_host_work_and_chip_calls(traced_mix):
     eng, _, _, _ = traced_mix
     s = eng.stats
@@ -244,3 +268,34 @@ def test_the_legacy_prefill_path_opens_its_spans(tmp_path):
     assert any(e[2] == "PjitFunction(serve_prefill)" for e in events)
     assert {e[2] for e in spans} >= {"serve.step", "serve.admit", "serve.prefill",
                                      "serve.decode", "serve.decode_group"}
+
+
+def test_the_mpmm_kernel_runs_in_every_quantized_call_and_no_other(
+    setup, tmp_path, monkeypatch
+):
+    """Where the dispatch rule picks the kernel (as on a TPU with no mesh;
+    interpret mode here), every W4/W8 call — and the bf16 target's W8-draft
+    spec rounds — lowers its projections to it, bf16 calls never do, and
+    ``mpmm_kernel_calls`` counts exactly the calls whose span says so."""
+    traces = []
+    kernel = mpmm_mod.mpmm_pallas
+    monkeypatch.setattr(layers, "mpmm_backend", lambda: "pallas")
+    monkeypatch.setattr(
+        mpmm_mod, "mpmm_pallas",
+        lambda *a, **kw: traces.append(kw["w_bits"]) or kernel(*a, **kw),
+    )
+    jax.clear_caches()  # the shared step programs were traced on the XLA path
+    try:
+        eng = _engine(setup)
+        reqs = _submit_mix(eng, setup[0].vocab)
+        _, events = _traced(tmp_path, lambda: _drive(eng))
+    finally:
+        jax.clear_caches()  # ... and must not keep the kernel for later tests
+    assert set(traces) == {4, 8}
+    assert all(r.done and len(r.out_tokens) == 6 for r in reqs)
+    paths = _call_paths(events)
+    for w_bits, draft_bits, path in paths:
+        quantized = w_bits < 16 or (draft_bits or 16) < 16
+        assert path == ("pallas" if quantized else "none")
+    kernel_calls = sum(p == "pallas" for _, _, p in paths)
+    assert kernel_calls > 0 and eng.stats.mpmm_kernel_calls == kernel_calls

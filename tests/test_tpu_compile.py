@@ -1,6 +1,7 @@
 """Ahead-of-time compiles of the served path's Pallas kernels for a described
 TPU v5e (no chip needed): paged decode and paged prefill/verify at kv4/kv8/
-kv16 and ``mpmm`` at W4/W8, all at llama3.2-3b widths.  Interpret mode cannot
+kv16 and ``mpmm`` at W4/W8, at llama3.2-3b widths, and ``mpmm`` at every
+yi-9b projection shape read from a stack of layers.  Interpret mode cannot
 see what the TPU compiler refuses (block shapes off the (8, 128) tiling,
 int8 shifts, VMEM limits); these compiles can.
 
@@ -8,6 +9,8 @@ The topology is described inside a module fixture: only one process at a
 time may load the TPU compiler's library, so describing it at import would
 break test collection under several workers.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -100,3 +103,32 @@ def test_mpmm_compiles(one_chip, w_bits, dataflow):
         s((1, n), jnp.float32),
     )
     assert "tpu_custom_call" in text
+
+
+YI = get_config("yi-9b")
+_MOVES = re.compile(r"= \w+\[([\d,]*)\]\S* (copy|pad|dynamic-slice)\(")
+
+
+@pytest.mark.parametrize("w_bits", [4, 8])
+@pytest.mark.parametrize(
+    "k,n",
+    [(YI.d_model, YI.n_heads * YI.hd), (YI.d_model, YI.n_kv_heads * YI.hd),
+     (YI.d_model, YI.d_ff), (YI.d_ff, YI.d_model), (YI.d_model, YI.vocab)],
+)
+def test_mpmm_reads_a_stacked_yi_9b_weight_in_place(one_chip, w_bits, k, n):
+    """Decode-sized rows against one layer of a 16-layer stack, the layer a
+    traced index: the kernel is in the program, and nothing the size of a
+    layer's weight is copied, padded or sliced on the way to it."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    kp = k // 2 if w_bits == 4 else k
+    text = _compiled_text(
+        lambda x, w, sc, li: ops.mpmm(x, w, sc, li, w_bits=w_bits, interpret=False),
+        s((16, k), jnp.bfloat16), s((16, kp, n), jnp.int8),
+        s((16, 1, n), jnp.float32), s((), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+    for dims, op in _MOVES.findall(text):
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        assert size < kp * n, (op, dims)
