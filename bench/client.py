@@ -146,6 +146,12 @@ class Client:
             with jax.profiler.TraceAnnotation("await-arrival"):
                 time.sleep(max(wake - time.perf_counter(), 0.0))
 
+    def run_steps(self, n: int) -> None:
+        """Closed loop: exactly ``n`` engine steps, whatever the clock reads."""
+        for _ in range(n):
+            self._submit_due(time.perf_counter())
+            self._step()
+
     def run_until_completed(self, n: int, t_limit: float) -> None:
         """Closed loop roll-forward: step until ``n`` requests completed."""
         while sum(1 for f in self.flights if f.done) < n:

@@ -1,7 +1,8 @@
 """One run of one cell: set-up, the measured window, the metrics, the check.
 
 Everything that belongs to a cell is found by name: the cell in
-``BENCHMARK.json``, its configuration file, its traffic file
+``BENCHMARK.json``, its configuration file and the block that file names
+(``bench/blocks/<block>.py``), its traffic file
 (``bench/traffic/<traffic>.json``), its limits (``bench/cells/<cell>.json``)
 and one reader per metric (``bench/metrics/<metric>.py``).
 """
@@ -152,8 +153,11 @@ def _roll_forward(client, mix: dict) -> None:
 
 # ------------------------------------------------------------------ run
 def run(p: dict, *, seed: int, seconds: float, trace: bool, t_start: float,
-        require_tpu: bool = True, control: bool = False) -> dict:
-    """One run; returns the result line's object (without printing it)."""
+        require_tpu: bool = True, control: bool = False,
+        steps: int | None = None) -> dict:
+    """One run; returns the result line's object (without printing it).
+    ``steps``: a closed loop's window of exactly that many engine steps in
+    place of ``seconds``, so that a run serves the same on any machine."""
     import jax
 
     from bench import check, devtrace, warmup
@@ -198,7 +202,10 @@ def run(p: dict, *, seed: int, seconds: float, trace: bool, t_start: float,
         jax.profiler.start_trace(trace_dir)
     with jax.profiler.TraceAnnotation(devtrace.WINDOW_SPAN):
         t0 = time.perf_counter()
-        client.run_until(t0 + seconds)
+        if steps is None:
+            client.run_until(t0 + seconds)
+        else:
+            client.run_steps(steps)
         t1 = time.perf_counter()
     if trace:
         jax.profiler.stop_trace()

@@ -2,11 +2,10 @@
 every request due in the window.  A request with no first token by the
 window's end enters at the time it has waited; a failed one as infinite.
 
-The median, and no higher percentile, because it is the highest one for which
-the window's requests (20 at this cell's rate and 50 s) leave ten or more
-samples beyond it; the 75th to 90th are printed beside it (info).  A per-layer
-metric of open-loop cells: over 20 requests its runs spread too widely for an
-end-to-end bound (PERF.md section 2)."""
+The median; the 75th to 90th are printed beside it (info).  A per-layer
+metric: where arrivals outrun the engine, every request waits in a queue that
+grows through the window, so the wait swings with the smallest change in speed
+(PERF.md section 2)."""
 import math
 
 from bench.measure import percentile, samples_beyond

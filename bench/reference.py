@@ -1,6 +1,7 @@
-"""Plain float32 reference of the served model: a dense pre-norm block with
-RMSNorm, rotary embeddings (rotate-half), grouped-query causal softmax
-attention and a SwiGLU MLP, as published for LLaMA-style models.
+"""Plain float32 reference of the served model, layer by layer as the
+configuration's block (``bench/blocks/<block>.py``) computes it, with the
+pieces every block shares: the embedding, RMSNorm, rotary embeddings, the
+head, and weights and caches at the precision a request states.
 
 It follows the precision a request states, and nothing of how the program
 computes it: weights of ``w_bits < 16`` are symmetric per-output-channel
@@ -72,53 +73,11 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-@functools.partial(jax.jit, static_argnames=("bits",))
-def layer_weights(blocks: dict, li: jnp.ndarray, bits: int) -> dict:
-    """Layer ``li`` of the stacked bf16 blocks, in float32 at ``bits``."""
-    take = lambda a: jax.lax.dynamic_index_in_dim(a, li, keepdims=False)
-    p = {k: take(blocks[k]) for k in ("norm1", "norm2", "wq", "wk", "wv", "wo")}
-    p.update({k: take(blocks["mlp"][k]) for k in ("wg", "wu", "wd")})
-    for k in ("wq", "wk", "wv", "wo", "wg", "wu", "wd"):
-        p[k] = quantize_weight(p[k], bits)
-    p["norm1"] = p["norm1"].astype(jnp.float32)
-    p["norm2"] = p["norm2"].astype(jnp.float32)
-    return p
-
-
-@functools.partial(jax.jit, static_argnames=("dims", "kv_bits"))
-def layer(x: jnp.ndarray, p: dict, dims: tuple, kv_bits: int) -> jnp.ndarray:
-    """One block over a whole sequence x [T, d] (causal; padding at the end
-    cannot reach earlier positions)."""
-    h, hkv, hd, theta, eps = dims
-    t = x.shape[0]
-    xn = rms_norm(x, p["norm1"], eps)
-    mm = lambda a, w: jnp.matmul(a, w, precision=HI)
-    q = rope(mm(xn, p["wq"]).reshape(t, h, hd), theta)
-    k = quantize_kv(rope(mm(xn, p["wk"]).reshape(t, hkv, hd), theta), kv_bits)
-    v = quantize_kv(mm(xn, p["wv"]).reshape(t, hkv, hd), kv_bits)
-    q = q.reshape(t, hkv, h // hkv, hd)
-    s = jnp.einsum("tkgd,skd->kgts", q, k, precision=HI) / np.sqrt(hd)
-    causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-    s = jnp.where(causal, s, -jnp.inf)
-    a = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("kgts,skd->tkgd", a, v, precision=HI).reshape(t, h * hd)
-    x = x + mm(o, p["wo"])
-    xn = rms_norm(x, p["norm2"], eps)
-    return x + mm(jax.nn.silu(mm(xn, p["wg"])) * mm(xn, p["wu"]), p["wd"])
-
-
 @functools.partial(jax.jit, static_argnames=("bits", "eps"))
 def head(x: jnp.ndarray, final_norm, unembed, bits: int, eps: float) -> jnp.ndarray:
     """Logits [n, V] of the final hidden states x [n, d]."""
     xn = rms_norm(x, final_norm.astype(jnp.float32), eps)
     return jnp.matmul(xn, quantize_weight(unembed, bits), precision=HI)
-
-
-def _dims(m: dict) -> tuple:
-    return (
-        m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"],
-        float(m["rope_theta"]), float(m["rms_norm_eps"]),
-    )
 
 
 def _padded(n: int, pad: int, fixed: int | None) -> int:
@@ -136,6 +95,9 @@ def logits(weights: dict, m: dict, seqs: list[np.ndarray], positions: list[np.nd
     layer so that one layer's float32 weights are live at a time.  Sequences
     and positions are padded to a multiple of ``pad``, or to ``seq_len`` and
     ``pos_len`` where given, so that a run compiles one shape of each."""
+    from bench import blocks  # here: the block modules import this one
+
+    block = blocks.of(m)
     embed = weights["embed"]
     xs = []
     for s in seqs:
@@ -143,10 +105,9 @@ def logits(weights: dict, m: dict, seqs: list[np.ndarray], positions: list[np.nd
         tok = np.zeros(t, np.int32)
         tok[: len(s)] = s
         xs.append(embed[jnp.asarray(tok)].astype(jnp.float32))
-    dims = _dims(m)
     for li in range(m["num_hidden_layers"]):
-        p = layer_weights(weights["blocks"], jnp.int32(li), w_bits)
-        xs = [layer(x, p, dims, kv_bits) for x in xs]
+        p = block.layer_weights(weights["blocks"], jnp.int32(li), w_bits)
+        xs = [block.layer(x, p, m, kv_bits) for x in xs]
         del p
     out = []
     for x, pos in zip(xs, positions):

@@ -58,7 +58,7 @@ def test_a_broken_timed_path_is_not_correct(fault, monkeypatch):
         r = harness.run(
             tiny.plan(tiny.TENANTS_MIX, limits=LIMITS, per_group=3), seed=2**31 + 43,
             seconds=1.5, trace=False, t_start=time.perf_counter(),
-            require_tpu=False,
+            require_tpu=False, steps=tiny.WINDOW_STEPS,
         )
     finally:
         monkeypatch.undo()

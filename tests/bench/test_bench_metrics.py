@@ -72,8 +72,12 @@ def test_itl_tok_s_and_setup():
     a = _flight(5.0, tokens=[9.0, 10.5, 11.0, 11.0, 20.5])  # gaps closing in window: 1.5, .5, 0
     b = _flight(12.0, tokens=[13.0, 15.0])
     run = _run([a, b])
-    assert reader("itl_p99_ms")(run) == pytest.approx(2000.0)
+    assert reader("itl_p99_ms.saturated")(run) == pytest.approx(2000.0)
     assert run.notes["itl_gaps"] == 4
+    # tokens at 10.5, 11, 11 (a) and 13, 15 (b) inside (10, 20]: 5 over 10 s
+    assert reader("tok_s")(run) == pytest.approx(0.5)
+    assert run.notes["window_tokens"] == 5
+    assert reader("tok_s")(_run([_flight(5.0, tokens=[9.0, 20.5])])) is None
     assert reader("setup_s")(run) == 3.5
 
 

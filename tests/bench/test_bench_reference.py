@@ -44,9 +44,15 @@ def _program_logits(weights, m, tokens, n_prompt, w_bits, kv_bits):
     return np.stack(out)
 
 
+# grouped-query (tiny.MODEL: 4 query heads over 2 KV heads) and multi-head
+# attention (as many KV heads as query heads: one query row per KV head)
+MODELS = {"gqa": tiny.MODEL, "mha": {**tiny.MODEL, "num_key_value_heads": 4}}
+
+
+@pytest.mark.parametrize("heads", sorted(MODELS))
 @pytest.mark.parametrize("w_bits,kv_bits", [(16, 16), (16, 8), (8, 8), (4, 4)])
-def test_reference_follows_prefill_then_decode_through_the_cache(w_bits, kv_bits):
-    m = tiny.MODEL
+def test_reference_follows_prefill_then_decode_through_the_cache(w_bits, kv_bits, heads):
+    m = MODELS[heads]
     weights = make_weights(m, 2**31 + 5)
     rng = np.random.default_rng(0)
     tokens = rng.integers(0, m["vocab_size"], 40).astype(np.int32)
